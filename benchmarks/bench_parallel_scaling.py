@@ -16,7 +16,7 @@ import sys
 import textwrap
 from pathlib import Path
 
-from .common import emit
+from .common import emit, require_cpu_emulation
 
 _WORKER = """
 import json, time
@@ -65,6 +65,7 @@ print(json.dumps({"p": p, "n": int(n), "t_full": t_full,
 
 
 def run(max_p: int = 4):
+    require_cpu_emulation("table5")
     print("# Table 5 / Figure 11: parallel scaling (subprocess per core count)")
     print(f"# NOTE: this host has {os.cpu_count()} physical core(s); fake "
           "devices multiplex it, so 'speedup' here validates the harness + "

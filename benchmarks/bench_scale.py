@@ -19,8 +19,9 @@ Per (backend x size) cell: cold wall (pays every trace), warm per-stage
 wall (median of ``iters``, barriers restored via ``stage_timings=True``),
 async warm wall, warm trace count (asserted zero), peak device memory
 where the platform reports it, achieved effective bandwidth against a
-one-pass byte model, and the fraction of the ``repro.launch.roofline``
-HBM roof that bandwidth represents.
+one-pass byte model, and — on a TPU only — the fraction of the chip's
+published HBM bandwidth (``common.PEAKS``, keyed by ``device_kind``) that
+bandwidth represents.  A CPU run leaves that field null.
 
 Byte model (one pass per stage — a deliberate lower bound, so the
 reported bytes/s never flatters):
@@ -43,9 +44,7 @@ import numpy as np
 from repro.core import plancache
 from repro.core.keyformat import KeySet
 from repro.core.pipeline import ReconstructionPipeline
-from repro.launch.roofline import HBM_BW
-
-from .common import emit, timed
+from .common import device_peaks, emit, timed
 
 DEFAULT_SIZES = (65536, 262144, 1048576 + 4096)  # 64k -> 1M+ (off-boundary)
 
@@ -122,6 +121,11 @@ def _measure_cell(
     total_bytes = sum(bmodel.values())
     stage_wall = warm["extract"] + warm["sort"] + warm["build"]
     achieved = total_bytes / max(stage_wall, 1e-9)
+    dev = jax.devices()[0]
+    roof = (
+        achieved / device_peaks(dev.device_kind)["hbm_bytes_per_s"]
+        if dev.platform == "tpu" else None
+    )
     per_stage_bw = {k: bmodel[k] / max(warm[k], 1e-9) for k in bmodel}
     row = {
         "name": row_name,
@@ -147,7 +151,7 @@ def _measure_cell(
         "peak_device_mem_bytes": _peak_device_mem(),
         "model_bytes": bmodel,
         "achieved_bytes_per_s": achieved,
-        "hbm_roof_fraction": achieved / HBM_BW,
+        "hbm_roof_fraction": roof,
         "per_stage_bytes_per_s": per_stage_bw,
         "plan_cache": plancache.cache_stats(),
     }
@@ -161,8 +165,7 @@ def _measure_cell(
         f"warm_sync={t_warm_sync:.4f}s;async_x={row['async_speedup']:.3f};"
         f"sort={warm['sort']:.4f}s;build={warm['build']:.4f}s;"
         f"chunked={row['chunked']};traces={warm_traces};"
-        f"GBps={achieved / 1e9:.2f};"
-        f"hbm_frac={row['hbm_roof_fraction']:.4f}",
+        f"GBps={achieved / 1e9:.2f};hbm_frac={roof}",
     )
     if assert_zero_warm_traces:
         assert warm_traces == 0, (

@@ -15,7 +15,7 @@ import sys
 import textwrap
 from pathlib import Path
 
-from .common import emit
+from .common import emit, require_cpu_emulation
 
 _WORKER = """
 import json, time
@@ -61,6 +61,7 @@ print(json.dumps({"p": p, "t_library": t_lib, "t_rowcolumn": t_rc}))
 
 
 def run():
+    require_cpu_emulation("table6")
     print("# Table 6 / Figure 13: row-column analogue vs monolithic lax.sort")
     src = str(Path(__file__).resolve().parents[1] / "src")
     for p in (1, 4):
@@ -93,7 +94,9 @@ def run():
     rng = np.random.default_rng(0)
     words = jnp.asarray(rng.integers(0, 2**32, size=(4096, 2), dtype=np.uint32))
     rids = jnp.arange(4096, dtype=jnp.uint32)
-    dt, _ = timed(lambda: bit_ops.block_sort(words, rids, block=512), iters=2)
+    dt, _ = timed(
+        lambda: bit_ops.block_sort(words, rids, block=512, interpret=True), iters=2
+    )
     emit("table6/bitonic_block_kernel_interpret", dt,
          "n=4096;W=2;block=512;interpret=True")
 

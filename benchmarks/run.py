@@ -10,8 +10,10 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
 import traceback
+from pathlib import Path
 
 
 def main() -> None:
@@ -43,6 +45,10 @@ def main() -> None:
         bench_sort_comparison,
         bench_zipf_sensitivity,
     )
+
+    from repro.core.plancache import enable_persistent_cache
+
+    enable_persistent_cache(Path(__file__).resolve().parents[1])
 
     scale = 0.05 if args.fast else 0.1
     suites = {
@@ -98,6 +104,7 @@ def main() -> None:
         except OSError as e:
             ap.error(f"cannot write --json target: {e}")
     payload: dict = {"suites": {}, "fast": args.fast}
+    failed = []
     print("name,us_per_call,derived")
     for name, fn in suites.items():
         if name not in only:
@@ -111,12 +118,16 @@ def main() -> None:
             print(f"# SUITE {name} FAILED")
             traceback.print_exc()
             payload["suites"][name] = {"error": traceback.format_exc()}
+            failed.append(name)
         print(f"# suite {name} took {time.time() - t0:.1f}s", flush=True)
 
     if args.json:
         with open(args.json, "w") as f:
             json.dump(payload, f, indent=2, sort_keys=True)
         print(f"# wrote {args.json}")
+    if failed:
+        print(f"# FAILED suites: {','.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 from repro.core.compress import ExtractionPlan, extract_bits
 from repro.core.dbits import rank_in_sorted_keyed
 from repro.core.distsort import make_sample_sort
@@ -321,7 +321,7 @@ class DistributedBackend(ExecutionBackend):
         def builder():
             body = _lookup_many_body(_leaf_match_many_full)
             spec = P(self.axis_name)  # shard the leading (tenant) axis
-            fn = shard_map(
+            fn = jax.shard_map(
                 body,
                 mesh=self.mesh,
                 in_specs=(spec, spec, spec),
@@ -372,7 +372,7 @@ class DistributedBackend(ExecutionBackend):
             local = jax.vmap(one, in_axes=(0, 0, 0))
             spec3 = P(self.axis_name, None, None)
             spec2 = P(self.axis_name, None)
-            fn = shard_map(
+            fn = jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=(spec3, spec2, spec2),

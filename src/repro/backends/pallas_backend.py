@@ -4,6 +4,10 @@ Extraction runs through ``repro.kernels.pext`` (the static shift/mask
 schedule over word planes); the sort runs the paper's row-column structure
 on one device: ``repro.kernels.bitonic`` sorts VMEM-sized blocks, then one
 ``lax.sort`` merges the block runs (Appendix A step 3.2's multiway merge).
+``merge_sorted`` has no kernel: the backend keeps the base class's XLA
+merge-path merge (``last_info["merge"] == "xla"``).  A lane gather has no
+Mosaic lowering, and the merge ladder's runs of millions of keys do not
+fit one VMEM block.
 
 ``interpret`` is auto-selected from the platform: on TPU the kernels are
 compiled by Mosaic; elsewhere the kernel *bodies* execute under the Pallas
@@ -22,15 +26,13 @@ import jax.numpy as jnp
 
 from repro.core.compress import ExtractionPlan
 from repro.core.dbits import sort_words_keyed
-from repro.core.plancache import get_cache, merge_padded, sort_padded
+from repro.core.plancache import get_cache, sort_padded
 from repro.kernels.bitonic import ops as bitonic_ops
 from repro.kernels.bitonic.kernel import DEFAULT_BLOCK
 from repro.kernels.build import ops as build_ops
 from repro.kernels.build.kernel import DEFAULT_TILE as BUILD_TILE
 from repro.kernels.lookup import ops as lookup_ops
 from repro.kernels.lookup.kernel import DEFAULT_TILE as LOOKUP_TILE
-from repro.kernels.merge import ops as merge_ops
-from repro.kernels.merge.kernel import DEFAULT_TILE as MERGE_TILE
 from repro.kernels.pext import ops as pext_ops
 from repro.kernels.pext.kernel import DEFAULT_TILE
 
@@ -41,7 +43,8 @@ __all__ = ["PallasBackend"]
 
 @register_backend("pallas")
 class PallasBackend(ExecutionBackend):
-    """kernels/pext extraction + kernels/bitonic block sort."""
+    """kernels/pext extraction + kernels/bitonic block sort; ``merge_sorted``
+    is the inherited XLA merge, with no kernel (see the module docstring)."""
 
     supports_batched = True
 
@@ -50,7 +53,6 @@ class PallasBackend(ExecutionBackend):
         interpret: bool | None = None,
         tile: int = DEFAULT_TILE,
         block: int = DEFAULT_BLOCK,
-        merge_tile: int = MERGE_TILE,
         build_tile: int = BUILD_TILE,
         lookup_tile: int = LOOKUP_TILE,
     ) -> None:
@@ -60,10 +62,9 @@ class PallasBackend(ExecutionBackend):
         self.interpret = bool(interpret)
         self.tile = int(tile)
         self.block = int(block)
-        self.merge_tile = int(merge_tile)
         self.build_tile = int(build_tile)
         self.lookup_tile = int(lookup_tile)
-        self.last_info = {"interpret": self.interpret}
+        self.last_info = {"interpret": self.interpret, "merge": "xla"}
 
     def extract(self, words: jnp.ndarray, plan: ExtractionPlan) -> jnp.ndarray:
         return pext_ops.pext(
@@ -89,25 +90,6 @@ class PallasBackend(ExecutionBackend):
             n_valid=n_valid, keep_padded=keep_padded, donate=donate,
         )
 
-    def merge_sorted(self, keys_a, rows_a, keys_b, rows_b, *,
-                     n_valid_a=None, n_valid_b=None, keep_padded=False,
-                     donate=False):
-        """kernels/merge tiled merge-path rank of the smaller run +
-        complement scatter, shape-bucketed (one compiled program per
-        (bucket_a, bucket_b)); donation rides on the outer jit."""
-        tile, interpret = self.merge_tile, self.interpret
-
-        def impl(ka, ra, kb, rb):
-            return merge_ops.merge_sorted(ka, ra, kb, rb, tile=tile, interpret=interpret)
-
-        return merge_padded(
-            jnp.asarray(keys_a, jnp.uint32), jnp.asarray(rows_a, jnp.uint32),
-            jnp.asarray(keys_b, jnp.uint32), jnp.asarray(rows_b, jnp.uint32),
-            backend=self.name, impl=impl, extra_key=(tile, interpret),
-            n_valid_a=n_valid_a, n_valid_b=n_valid_b,
-            keep_padded=keep_padded, donate=donate,
-        )
-
     def build(self, comp_sorted, row_sorted, meta, words, lengths, config,
               rids=None, n_valid=None, donate=False):
         """Cached build programs with the kernels/build tiled pk-window
@@ -117,7 +99,7 @@ class PallasBackend(ExecutionBackend):
         return build_btree(
             comp_sorted, row_sorted, meta, words, lengths, config, rids=rids,
             backend_name=self.name,
-            slice_fn=build_ops.slice_fn(tile=self.build_tile, interpret=self.interpret),
+            slice_fn=build_ops.slice_fn(interpret=self.interpret, tile=self.build_tile),
             program_key_extra=(self.build_tile, self.interpret),
             n_valid=n_valid, donate=donate,
         )

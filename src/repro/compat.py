@@ -1,72 +1,21 @@
-"""Compatibility shims over jax API drift.
+"""The one mesh constructor every module shares.
 
-The repo targets current jax (``jax.shard_map``, ``jax.make_mesh(...,
-axis_types=...)``, ``jax.sharding.AxisType``); the pinned container image
-ships an older release where ``shard_map`` still lives under
-``jax.experimental`` and meshes have no axis types.  Every module that
-builds a mesh or shard_maps goes through this file so the whole tree moves
-between versions with one edit.
+``jax.make_mesh`` gives its axes ``AxisType.Explicit`` unless told
+otherwise; the shard_map programs and sharding rules here are written for
+``Auto`` axes, so every mesh in the tree is built through this function.
 """
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["shard_map", "make_mesh", "set_mesh", "optimization_barrier"]
-
-
-# jax < 0.5 has no differentiation (nor transpose) rule for
-# optimization_barrier; this custom_vjp barriers the primal AND the
-# cotangent, so the scheduling pin holds in both the forward and backward
-# streams (hoisting the bf16 cast out of either direction doubles ICI
-# bytes).  custom_vjp because the bwd is plain code — a barriered tangent
-# under custom_jvp would need the transpose rule old jax also lacks.
-# Forward-mode jvp is not supported through this shim (nothing here uses
-# it).  No import-time jax execution: defining a custom_vjp touches no
-# device state.
-@jax.custom_vjp
-def optimization_barrier(x):
-    return jax.lax.optimization_barrier(x)
-
-
-def _optimization_barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _optimization_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-optimization_barrier.defvjp(_optimization_barrier_fwd, _optimization_barrier_bwd)
-
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax < 0.5: experimental namespace
-    from jax.experimental.shard_map import shard_map  # type: ignore[no-redef]
-
-
-def set_mesh(mesh: jax.sharding.Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` where it exists; on older jax the ``Mesh`` object is
-    itself the context manager that installs the physical mesh.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+__all__ = ["make_mesh"]
 
 
 def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(axis_shapes),
-                tuple(axis_names),
-                axis_types=(axis_type.Auto,) * len(tuple(axis_names)),
-            )
-        except TypeError:
-            pass
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+    )

@@ -31,7 +31,6 @@ __all__ = [
     "sort_words",
     "sort_words_keyed",
     "rank_in_sorted_keyed",
-    "merge_from_ranks",
     "merge_words_keyed",
     "adjacent_dbit_positions",
     "dbit_position_pairwise",
@@ -149,25 +148,22 @@ def rank_in_sorted_keyed(
     return lo
 
 
-def merge_from_ranks(
+def merge_words_keyed(
     keys_a: jnp.ndarray,
     rows_a: jnp.ndarray,
     keys_b: jnp.ndarray,
     rows_b: jnp.ndarray,
-    rank_fn=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Merge two ascending (key, row) runs given a rank primitive.
+    """Merge two runs that are each ascending in (key, row) order.
 
-    ``rank_fn(keys_s, rows_s, keys_q, rows_q)`` must return the rank of
-    each query pair in the sorted run (#{s < q}); the merge is then a
-    permutation scatter: each element's output position is its own index
-    plus its rank in the other run.  Rows must be distinct across the two
-    runs so the (key, row) order is total and the scatter collision-free.
-    The default primitive is ``rank_in_sorted_keyed``; the Pallas backend
-    passes its tiled rank kernel instead.
+    Byte-identical to ``sort_words_keyed`` over the concatenated pairs —
+    rows must be distinct across both runs, so the (key, row) order is total
+    and the merge is a permutation scatter: each element's output position
+    is its own index plus its rank in the other run (O(n log n) comparisons
+    for the ranks vs the full sort's network; O(n) data movement).  This is
+    the reference semantics of the backend ``merge_sorted`` op, and the
+    merge every single-device backend runs.
     """
-    if rank_fn is None:
-        rank_fn = rank_in_sorted_keyed
     keys_a = jnp.asarray(keys_a, jnp.uint32)
     keys_b = jnp.asarray(keys_b, jnp.uint32)
     rows_a = jnp.asarray(rows_a, jnp.uint32)
@@ -194,7 +190,7 @@ def merge_from_ranks(
     n, w = na + nb, int(keys_a.shape[1])
     pos_s = (
         jnp.arange(n_small, dtype=jnp.int32)
-        + rank_fn(big_k, big_r, small_k, small_r)
+        + rank_in_sorted_keyed(big_k, big_r, small_k, small_r)
     )
     occ = jnp.zeros((n,), jnp.int32).at[pos_s].set(1)
     # number of ranked (small-run) elements strictly before each position
@@ -213,23 +209,6 @@ def merge_from_ranks(
         big_r[big_idx],
     )
     return keys, rows
-
-
-def merge_words_keyed(
-    keys_a: jnp.ndarray,
-    rows_a: jnp.ndarray,
-    keys_b: jnp.ndarray,
-    rows_b: jnp.ndarray,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Merge two runs that are each ascending in (key, row) order.
-
-    Byte-identical to ``sort_words_keyed`` over the concatenated pairs —
-    rows must be distinct across both runs, so the (key, row) order is total
-    and the merge is a permutation scatter (O(n log n) comparisons for the
-    ranks vs the full sort's network; O(n) data movement).  This is the jnp
-    reference semantics of the backend ``merge_sorted`` op.
-    """
-    return merge_from_ranks(keys_a, rows_a, keys_b, rows_b)
 
 
 # ---------------------------------------------------------------------------

@@ -34,8 +34,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .dbits import sort_words, sort_words_keyed
 
 __all__ = ["DistSortResult", "sample_sort", "make_sample_sort"]
@@ -175,7 +173,7 @@ def make_sample_sort(mesh: Mesh, axis_name: str, n_per_shard: int, n_words: int,
         total_overflow = jax.lax.psum(overflow, axis_name)
         return mk, mr, mv.astype(jnp.bool_), total_overflow
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(axis_name, None), P(axis_name)),

@@ -34,6 +34,7 @@ __all__ = [
     "decode_decimal",
     "KeySet",
     "keys_to_words",
+    "pack_rows",
     "words_to_bytes",
 ]
 
@@ -224,6 +225,21 @@ def keys_to_words(
     for i, k in enumerate(key_list):
         buf[i, : len(k)] = np.frombuffer(k, dtype=np.uint8)
         lengths[i] = len(k)
+    return pack_rows(buf, lengths, rids)
+
+
+def pack_rows(
+    rows: np.ndarray,
+    lengths: np.ndarray | None = None,
+    rids: Sequence[int] | None = None,
+) -> KeySet:
+    """Pack (n, L) uint8 key bytes (zero-padded) into a (n, ceil(L/4))
+    uint32 ``KeySet`` in bulk.  ``lengths`` defaults to ``L`` for every
+    key, ``rids`` to the row index."""
+    n, width = rows.shape
+    n_words = max(1, (width + 3) // 4)
+    buf = np.zeros((n, n_words * 4), dtype=np.uint8)
+    buf[:, :width] = rows
     words = buf.reshape(n, n_words, 4)
     # big-endian within each word: byte 0 is the most significant
     words = (
@@ -232,11 +248,13 @@ def keys_to_words(
         | words[..., 2].astype(np.uint32) << 8
         | words[..., 3].astype(np.uint32)
     )
+    if lengths is None:
+        lengths = np.full((n,), width, np.int32)
     if rids is None:
         rid_arr = np.arange(n, dtype=np.uint32)
     else:
         rid_arr = np.asarray(rids, dtype=np.uint32)
-    return KeySet(words=words, lengths=lengths, rids=rid_arr)
+    return KeySet(words=words, lengths=np.asarray(lengths, np.int32), rids=rid_arr)
 
 
 def words_to_bytes(words: np.ndarray, length: int | None = None) -> bytes:

@@ -49,6 +49,11 @@ while JAX traces it, so the counter increments exactly once per compile).
 ``assert cache.stats()["traces"]`` unchanged across a call is the strong
 form of "zero recompilations" the regression tests use.
 
+Under the in-process cache sits JAX's persistent compilation cache on
+disk: :func:`enable_persistent_cache` places it, so a new process (a
+restart, the next run of a script) loads compiled programs instead of
+compiling them again.
+
 Long-lived servers can bound the cache: ``PlanCache(max_programs=N)``
 evicts the least-recently-used program past the bound (``evictions``
 counts them; an evicted program that is needed again simply rebuilds and
@@ -61,6 +66,7 @@ the thrash signature of a bound set below the working set — doubling
 from __future__ import annotations
 
 import math
+import os
 import threading
 import time
 import warnings
@@ -87,6 +93,7 @@ __all__ = [
     "cache_stats",
     "scoped_cache",
     "donation_supported",
+    "enable_persistent_cache",
     "const_full",
     "iota_u32",
     "pad_tail",
@@ -419,6 +426,23 @@ def donation_supported() -> bool:
     return _DONATION_SUPPORTED
 
 
+def enable_persistent_cache(root: str | os.PathLike) -> str:
+    """Place JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and no other path is set here.  Otherwise the cache lives at the
+    fixed path ``<root>/.jax_cache`` — never a temporary name, a process id
+    or a time, since a directory that moves between runs never hits.
+    Programs that compile in under a second are not worth a disk entry and
+    are left out (JAX's default threshold).
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.abspath(root), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 # ---------------------------------------------------------------------------
 # padding helpers — cached fill constants + one dynamic_update_slice; no
 # per-call jnp.concatenate / jnp.full on the warm path
@@ -611,8 +635,6 @@ def merge_padded(
     rows_b: jnp.ndarray,
     *,
     backend: str = "jnp",
-    impl: Callable | None = None,
-    extra_key: tuple = (),
     cache: PlanCache | None = None,
     n_valid_a: int | None = None,
     n_valid_b: int | None = None,
@@ -655,10 +677,7 @@ def merge_padded(
         rows_b = pad_tail(jnp.asarray(rows_b, jnp.uint32), bb, 0)
     else:
         nb, bb = int(n_valid_b), int(keys_b.shape[0])
-    if impl is None:
-        from .dbits import merge_words_keyed
-
-        impl = merge_words_keyed
+    from .dbits import merge_words_keyed
 
     don = bool(donate) and donation_supported()
     jit_kwargs = {"donate_argnums": (0, 1, 2, 3)} if don else {}
@@ -667,11 +686,11 @@ def merge_padded(
         def prog(ka, ra, kb, rb, nva, nvb):
             ka, ra = _mask_run(ka, ra, nva, ROW_PAD_A)
             kb, rb = _mask_run(kb, rb, nvb, ROW_PAD_B)
-            return impl(ka, ra, kb, rb)
+            return merge_words_keyed(ka, ra, kb, rb)
 
         return cache.jit(prog, **jit_kwargs)
 
-    prog = cache.program(("merge", backend, ba, bb, w, don) + extra_key, builder)
+    prog = cache.program(("merge", backend, ba, bb, w, don), builder)
     km, rm = prog(keys_a, rows_a, keys_b, rows_b, np.uint32(na), np.uint32(nb))
     if keep_padded:
         return km, rm

@@ -17,7 +17,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.configs.paper_index import IndexDatasetConfig, ZipfConfig
-from repro.core.keyformat import KeySet, keys_to_words
+from repro.core.keyformat import KeySet, keys_to_words, pack_rows
 
 __all__ = ["zipf_keys", "dataset_keys", "lm_tokens"]
 
@@ -101,24 +101,39 @@ def _title_like(rng, n, max_len):
 
 def _fixed_record(rng, n, width):
     """INDBTAB/Part-like: fixed-width multi-column business keys — a few
-    low-cardinality columns + a sequence column (most bits invariant)."""
-    out = np.zeros((n, width), dtype=np.uint8)
-    out[:, :] = ord("0")
-    doc = rng.integers(0, 10000, n)
-    item = rng.integers(0, 100, n)
-    seq = np.arange(n)
-    for i in range(n):
-        s = f"{2024:04d}{int(doc[i]):08d}{int(item[i]):04d}{int(seq[i]):010d}"
-        b = s.encode()[:width]
-        out[i, : len(b)] = np.frombuffer(b, np.uint8)
-    return [bytes(r) for r in out]
+    low-cardinality columns + a sequence column (most bits invariant).
+
+    Returns the (n, width) uint8 ASCII rows in ascending byte order:
+    ``year(4) doc(8) item(4) seq(10)`` zero-padded decimal digits, then
+    ``"0"`` fill.  Built one digit column at a time, so a table of tens of
+    millions of rows takes seconds.  The seq column makes every row
+    distinct, and fixed-width digits make byte order equal the numeric
+    order of ``(doc, item, seq)``.
+    """
+    fields = (
+        (np.full(n, 2024), 4),
+        (rng.integers(0, 10000, n), 8),
+        (rng.integers(0, 100, n), 4),
+        (np.arange(n), 10),
+    )
+    if width < sum(d for _, d in fields):
+        raise ValueError(f"fixed records need 26 bytes, got {width}")
+    out = np.full((n, width), ord("0"), np.uint8)
+    col = 0
+    for v, n_digits in fields:
+        for i in range(n_digits):
+            out[:, col] = ord("0") + (v // 10 ** (n_digits - 1 - i)) % 10
+            col += 1
+    _, doc, item, seq = (v for v, _ in fields)
+    return out[np.lexsort((seq, item, doc))]
 
 
 def dataset_keys(cfg: IndexDatasetConfig, seed: int = 0) -> KeySet:
     rng = np.random.default_rng(seed)
     if cfg.kind == "fixed":
-        keys = _fixed_record(rng, cfg.n_keys, cfg.key_bytes)
-    elif cfg.kind == "url":
+        rows = _fixed_record(rng, cfg.n_keys, cfg.key_bytes)
+        return pack_rows(rows[rng.permutation(rows.shape[0])])
+    if cfg.kind == "url":
         keys = _url_like(rng, cfg.n_keys, cfg.key_bytes, cfg.key_bytes * 2)
     elif cfg.kind == "title":
         keys = _title_like(rng, cfg.n_keys, cfg.key_bytes * 3)

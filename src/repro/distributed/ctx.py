@@ -18,7 +18,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import set_mesh
 
 _state = threading.local()
 
@@ -32,7 +31,7 @@ def use_mesh(mesh: Mesh, data_axes=("data",), model_axis: str = "model"):
     prev = getattr(_state, "ctx", None)
     _state.ctx = (mesh, tuple(data_axes), model_axis)
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _state.ctx = prev

@@ -12,10 +12,12 @@ The comparator is the multiword lexicographic order (word 0 most
 significant) — the same comparator the paper's sort uses, so compressing
 keys shrinks ``W`` and with it the cost of *every* substage.
 
-The partner exchange ``idx ^ j`` is a static permutation per substage; we
-express it with `jnp.take` along the lane axis (interpret-validated; on
-real TPU hardware Mosaic lowers power-of-two strided gathers to cheap
-in-register shuffles for j >= 128-lane strides and VMEM swizzles below).
+The partner exchange ``idx ^ j`` is two lane rotations per substage: lanes
+with ``i & j == 0`` read their partner from ``roll(x, T - j)`` (lane
+``i + j``), the others from ``roll(x, j)`` (lane ``i - j``).  Mosaic has no
+lowering for a lane gather (``jnp.take``), but rotates lanes natively.
+Every mask is a 2-D ``(1, T)`` vector built from integer ops: Mosaic does
+not legalize a compare of two bool vectors, nor 1-D bool vectors.
 """
 
 from __future__ import annotations
@@ -26,17 +28,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK = 512
 
 
 def _lex_cmp_planes(a, b, n_key_words: int):
-    """a, b: (W, T) planes -> ((T,) a<b, (T,) a==b) lexicographic over key words."""
-    less = jnp.zeros(a.shape[1], jnp.bool_)
-    eq = jnp.ones(a.shape[1], jnp.bool_)
-    for w in range(n_key_words):
-        less = less | (eq & (a[w] < b[w]))
-        eq = eq & (a[w] == b[w])
+    """a, b: (W, T) planes -> ((1, T) a<b, (1, T) a==b) lexicographic over
+    the first ``n_key_words`` words."""
+    less = a[0:1] < b[0:1]
+    eq = a[0:1] == b[0:1]
+    for w in range(1, n_key_words):
+        aw, bw = a[w : w + 1], b[w : w + 1]
+        less = less | (eq & (aw < bw))
+        eq = eq & (aw == bw)
     return less, eq
 
 
@@ -44,27 +49,27 @@ def _bitonic_kernel(n_key_words: int, block: int, x_ref, o_ref):
     """x_ref/o_ref: (W+1, block) planes — key words + rid payload plane.
 
     Per substage, every lane decides *keep mine vs take partner's* from a
-    lane-local comparison.  With ``want_le = (is_lo == ascending)``:
-        keep = want_le ? (x <= p) : (x > p)   [ > as !(<=) with eq split ]
+    lane-local comparison.  Lane ``i`` wants the smaller of the pair when
+    bit ``sub`` and bit ``stage`` of ``i`` agree (the low lane of an
+    ascending pair or the high lane of a descending one):
+        keep = want_le ? (x <= p) : (x > p)   [ > as !(<) with eq split ]
     Ties keep both lanes' own entries (no payload duplication).
     """
     x = x_ref[...]
     # iota must be materialized in-kernel (captured constants are rejected)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (block,), 0)
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
     log_t = int(np.log2(block))
     for stage in range(1, log_t + 1):
-        k = 1 << stage
         for sub in range(stage - 1, -1, -1):
             j = 1 << sub
-            partner = idx ^ j
-            px = jnp.take(x, partner, axis=1)
-            ascending = (idx & k) == 0
             is_lo = (idx & j) == 0
+            want_le = (((idx >> sub) ^ (idx >> stage)) & 1) == 0
+            px = jnp.where(
+                is_lo, pltpu.roll(x, block - j, 1), pltpu.roll(x, j, 1)
+            )
             lt, eq = _lex_cmp_planes(x, px, n_key_words)
-            le = lt | eq
-            want_le = is_lo == ascending
-            keep = jnp.where(want_le, le, ~lt)
-            x = jnp.where(keep[None, :], x, px)
+            keep = (want_le & (lt | eq)) | (~want_le & ~lt)
+            x = jnp.where(keep, x, px)
     o_ref[...] = x
 
 
@@ -72,15 +77,18 @@ def _bitonic_kernel(n_key_words: int, block: int, x_ref, o_ref):
 def bitonic_block_sort_planes(
     planes: jnp.ndarray,
     n_key_words: int,
+    *,
+    interpret: bool,
     block: int = DEFAULT_BLOCK,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Sort each block of ``block`` lanes independently.
 
     planes: (W+1, n) uint32 — key word planes then one rid plane; ``n`` a
     multiple of ``block``.  Returns same shape, each block sorted by the
-    first ``n_key_words`` planes (stably w.r.t. nothing — ties broken by
-    nothing; pad rid plane participates only as payload).
+    first ``n_key_words`` planes (not stable: ties keep whichever rid the
+    network leaves; the pad rid plane participates only as payload).
+    ``interpret=True`` runs the kernel body under the Pallas interpreter
+    (CPU tests); the TPU path passes ``False``.
     """
     wp, n = planes.shape
     assert n % block == 0 and (block & (block - 1)) == 0, (n, block)

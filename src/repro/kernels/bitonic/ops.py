@@ -16,8 +16,9 @@ _SENTINEL = np.uint32(0xFFFFFFFF)
 def block_sort(
     words: jnp.ndarray,
     rids: jnp.ndarray,
+    *,
+    interpret: bool,
     block: int = DEFAULT_BLOCK,
-    interpret: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Sort each ``block`` of rows of (n, W) keys + (n,) rid payload.
 

@@ -64,8 +64,9 @@ def pk_window_planes(
     word_planes: jnp.ndarray,
     starts: jnp.ndarray,
     pk: int,
+    *,
+    interpret: bool,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(W, n) uint32 key word planes + (n,) int32 starts -> (n,) uint32
     pk-bit windows.  ``n`` must be a multiple of ``tile``."""

@@ -13,8 +13,9 @@ def pk_windows(
     words: jnp.ndarray,
     starts: jnp.ndarray,
     pk: int,
+    *,
+    interpret: bool,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(m, W) uint32 keys + (m,) start bit positions -> (m,) uint32 windows.
 
@@ -34,7 +35,7 @@ def pk_windows(
     return out[:m]
 
 
-def slice_fn(tile: int = DEFAULT_TILE, interpret: bool = True):
+def slice_fn(*, interpret: bool, tile: int = DEFAULT_TILE):
     """A ``build_btree(slice_fn=...)``-shaped closure over kernel options."""
 
     def fn(words, starts, pk):

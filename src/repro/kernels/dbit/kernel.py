@@ -45,8 +45,9 @@ def _dbit_kernel(n_words: int, a_ref, b_ref, o_ref):
 def dbit_planes(
     prev_planes: jnp.ndarray,
     cur_planes: jnp.ndarray,
+    *,
+    interpret: bool,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(W, n) x2 -> (n,) int32 distinction bit positions (NO_DBIT if equal)."""
     w, n = prev_planes.shape

@@ -10,7 +10,7 @@ from .kernel import DEFAULT_TILE, dbit_planes
 
 
 def adjacent_dbits(
-    sorted_words: jnp.ndarray, tile: int = DEFAULT_TILE, interpret: bool = True
+    sorted_words: jnp.ndarray, *, interpret: bool, tile: int = DEFAULT_TILE
 ) -> jnp.ndarray:
     """(n, W) sorted keys -> (n-1,) adjacent distinction bit positions.
 

@@ -61,8 +61,9 @@ def probe_planes(
     starts: jnp.ndarray,
     entry_pk: jnp.ndarray,
     pk: int,
+    *,
+    interpret: bool,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(W, n) query word planes + (n,) starts + (n,) stored partial keys
     -> (n,) uint32 candidate mask.  ``n`` must be a multiple of ``tile``."""
@@ -117,8 +118,9 @@ def probe_planes_many(
     starts: jnp.ndarray,
     entry_pk: jnp.ndarray,
     pk: int,
+    *,
+    interpret: bool,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(T, W, n) stacked query word planes + (T, n) starts/partial keys
     -> (T, n) uint32 candidate mask.

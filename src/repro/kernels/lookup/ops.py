@@ -15,8 +15,9 @@ def probe(
     starts: jnp.ndarray,
     entry_pk: jnp.ndarray,
     pk: int,
+    *,
+    interpret: bool,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(m, W) query keys + (m,) window starts + (m,) stored partial keys
     -> (m,) bool candidate mask (query window == stored partial key).
@@ -37,7 +38,7 @@ def probe(
     return out[:m].astype(bool)
 
 
-def leaf_match_fn(tile: int = DEFAULT_TILE, interpret: bool = True):
+def leaf_match_fn(*, interpret: bool, tile: int = DEFAULT_TILE):
     """A ``lookup_batch_planned(leaf_match_fn=...)``-shaped closure.
 
     Screens every (query, leaf entry) pair with the probe kernel, then
@@ -69,8 +70,9 @@ def probe_many(
     starts: jnp.ndarray,
     entry_pk: jnp.ndarray,
     pk: int,
+    *,
+    interpret: bool,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(T, m, W) stacked pair queries + (T, m) starts/partial keys
     -> (T, m) bool candidate mask — the tenant-major twin of :func:`probe`.
@@ -93,7 +95,7 @@ def probe_many(
     return out[:, :m].astype(bool)
 
 
-def leaf_match_many_fn(tile: int = DEFAULT_TILE, interpret: bool = True):
+def leaf_match_many_fn(*, interpret: bool, tile: int = DEFAULT_TILE):
     """A ``lookup_many_planned(leaf_match_many_fn=...)``-shaped closure.
 
     The stacked twin of :func:`leaf_match_fn`: per-tenant gathers of the

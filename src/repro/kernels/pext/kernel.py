@@ -49,13 +49,15 @@ def _pext_kernel(plan: ExtractionPlan, x_ref, o_ref):
 def pext_planes(
     planes: jnp.ndarray,
     plan: ExtractionPlan,
+    *,
+    interpret: bool,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(W, n) uint32 word planes -> (Wc, n) compressed word planes.
 
-    ``n`` must be a multiple of ``tile`` (ops.py pads).  interpret=True runs
-    the kernel body on CPU for validation; on TPU pass interpret=False.
+    ``n`` must be a multiple of ``tile`` (ops.py pads).  ``interpret=True``
+    runs the kernel body under the Pallas interpreter (CPU tests); the TPU
+    path passes ``False``.
     """
     w, n = planes.shape
     assert n % tile == 0, (n, tile)

@@ -13,8 +13,9 @@ from .kernel import DEFAULT_TILE, pext_planes
 def pext(
     words: jnp.ndarray,
     plan: ExtractionPlan,
+    *,
+    interpret: bool,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(n, W) uint32 keys -> (n, Wc) uint32 compressed keys.
 

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import optimization_barrier
 
 # ---------------------------------------------------------------------------
 # norms / activations / rope
@@ -29,7 +28,7 @@ def rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     # pin the bf16 cast here: without the barrier XLA hoists the fp32->bf16
     # convert past the TP collectives and moves activations over ICI in
     # fp32 — 2x the wire bytes (EXPERIMENTS.md §Perf i3)
-    return optimization_barrier(out)
+    return jax.lax.optimization_barrier(out)
 
 
 def silu(x):

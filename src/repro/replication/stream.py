@@ -871,12 +871,17 @@ class StreamReplica:
             self.n_batches_applied += len(pending)
             self.n_rebuilds += 1
             return st
-        # no index yet: accumulate the genesis prefix until a row survives
+        # no index yet: accumulate the genesis prefix until a row survives.
+        # Bring up from that prefix alone and fold the batches after it
+        # incrementally, as a tailing replica does: a fresh build over the
+        # whole span would drop the D-bits that deletes leave standing.
         logs = ([self._genesis] if self._genesis is not None else []) + pending
-        genesis = logs[0] if len(logs) == 1 else ChangeLog.concat(logs)
-        keep, words, lengths, rids = genesis.fold(np.zeros(0, np.uint32))
-        del keep
-        if words.shape[0] == 0:
+        for i in range(len(logs)):
+            genesis = logs[0] if i == 0 else ChangeLog.concat(logs[: i + 1])
+            _, words, lengths, rids = genesis.fold(np.zeros(0, np.uint32))
+            if words.shape[0]:
+                break
+        else:
             self._genesis = genesis
             return None
         self.replica = Replica(
@@ -887,8 +892,11 @@ class StreamReplica:
             applied_lsn=genesis.next_lsn - 1,
         )
         self._genesis = None
-        self.n_batches_applied += len(pending)
         self.n_rebuilds += 1
+        rest = logs[i + 1 :]
+        self.n_batches_applied += len(pending) - len(rest)
+        if rest:
+            return self._apply_pending(rest)
         return {"bring_up": True, "n_keys": words.shape[0]}
 
     # ----------------------------------------------------------- catch-up

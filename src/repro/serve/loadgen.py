@@ -683,9 +683,7 @@ def run_multitenant_load(
         TenantRegistry,
     )
 
-    backend_obj = get_backend(
-        backend, **({"interpret": True} if backend == "pallas" else {})
-    )
+    backend_obj = get_backend(backend)
     registry = TenantRegistry()
     slo = (
         None

@@ -26,7 +26,7 @@ def test_pext_kernel_sweep(rng, n, w):
     jw = jnp.asarray(arr)
     bm = D.compute_dbitmap(jw)
     plan = C.make_plan(np.asarray(bm), w)
-    got = pext_ops.pext(jw, plan, tile=256)
+    got = pext_ops.pext(jw, plan, tile=256, interpret=True)
     want = pext_ref(jw, plan)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -37,7 +37,7 @@ def test_pext_tile_shapes(rng, tile):
     jw = jnp.asarray(arr)
     bm = D.compute_dbitmap(jw)
     plan = C.make_plan(np.asarray(bm), 2)
-    got = pext_ops.pext(jw, plan, tile=tile)
+    got = pext_ops.pext(jw, plan, tile=tile, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(pext_ref(jw, plan)))
 
 
@@ -47,7 +47,7 @@ def test_pext_wide_keys(rng):
     jw = jnp.asarray(arr)
     bm = D.compute_dbitmap(jw)
     plan = C.make_plan(np.asarray(bm), 128)
-    got = pext_ops.pext(jw, plan, tile=128)
+    got = pext_ops.pext(jw, plan, tile=128, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(pext_ref(jw, plan)))
 
 
@@ -56,7 +56,9 @@ def test_pext_wide_keys(rng):
 def test_bitonic_kernel_sweep(rng, n, w, block):
     arr = _keys(rng, n, w, 0xFFFF00FF)
     rids = np.arange(n, dtype=np.uint32)
-    kw, kr = bitonic_ops.block_sort(jnp.asarray(arr), jnp.asarray(rids), block=block)
+    kw, kr = bitonic_ops.block_sort(
+        jnp.asarray(arr), jnp.asarray(rids), block=block, interpret=True
+    )
     kwn, krn = np.asarray(kw), np.asarray(kr)
     for s in range(0, n, block):
         e = min(s + block, n)
@@ -70,7 +72,9 @@ def test_bitonic_matches_ref_block_content(rng):
     n, w, block = 1024, 2, 256
     arr = _keys(rng, n, w, 0x0000FFFF)
     rids = np.arange(n, dtype=np.uint32)
-    kw, _ = bitonic_ops.block_sort(jnp.asarray(arr), jnp.asarray(rids), block=block)
+    kw, _ = bitonic_ops.block_sort(
+        jnp.asarray(arr), jnp.asarray(rids), block=block, interpret=True
+    )
     rw, _ = block_sort_ref(jnp.asarray(arr), jnp.asarray(rids), block)
     np.testing.assert_array_equal(np.asarray(kw), np.asarray(rw))
 
@@ -80,7 +84,9 @@ def test_bitonic_duplicate_keys(rng):
     n, block = 512, 128
     arr = np.repeat(_keys(rng, n // 4, 2, 0x000000FF), 4, axis=0)
     rids = np.arange(n, dtype=np.uint32)
-    kw, kr = bitonic_ops.block_sort(jnp.asarray(arr), jnp.asarray(rids), block=block)
+    kw, kr = bitonic_ops.block_sort(
+        jnp.asarray(arr), jnp.asarray(rids), block=block, interpret=True
+    )
     assert sorted(np.asarray(kr).tolist()) == rids.tolist()
 
 
@@ -88,14 +94,14 @@ def test_bitonic_duplicate_keys(rng):
 def test_dbit_kernel_sweep(rng, n, w):
     arr = _keys(rng, n, w, 0x0FFFFFFF)
     (sw,) = D.sort_words(jnp.asarray(arr))
-    got = dbit_ops.adjacent_dbits(sw, tile=256)
+    got = dbit_ops.adjacent_dbits(sw, tile=256, interpret=True)
     want = adjacent_dbits_ref(sw)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_dbit_kernel_duplicates():
     arr = jnp.asarray(np.asarray([[1, 2], [1, 2], [1, 3]], np.uint32))
-    got = np.asarray(dbit_ops.adjacent_dbits(arr, tile=128))
+    got = np.asarray(dbit_ops.adjacent_dbits(arr, tile=128, interpret=True))
     assert got[0] == D.NO_DBIT  # equal adjacent keys
     assert got[1] == 63  # 2 vs 3 differ in the last bit of word 1
 
@@ -108,12 +114,12 @@ def test_kernel_pipeline_end_to_end(rng):
     jw = jnp.asarray(arr)
     bm = D.compute_dbitmap(jw)
     plan = C.make_plan(np.asarray(bm), w)
-    comp_k = pext_ops.pext(jw, plan, tile=256)
+    comp_k = pext_ops.pext(jw, plan, tile=256, interpret=True)
     rids = jnp.arange(arr.shape[0], dtype=jnp.uint32)
-    bw, br = bitonic_ops.block_sort(comp_k, rids, block=256)
+    bw, br = bitonic_ops.block_sort(comp_k, rids, block=256, interpret=True)
     # final merge of block runs
     (ms, mr) = D.sort_words(bw, br)
-    dp_k = dbit_ops.adjacent_dbits(ms, tile=256)
+    dp_k = dbit_ops.adjacent_dbits(ms, tile=256, interpret=True)
     # oracle pipeline
     comp_o = C.extract_bits(jw, plan)
     (so, ro) = D.sort_words(comp_o, rids)

@@ -117,3 +117,32 @@ def test_keys_to_words_roundtrip():
     ks = KF.keys_to_words(keys)
     for i, k in enumerate(keys):
         assert KF.words_to_bytes(ks.words[i], int(ks.lengths[i])) == k
+
+
+def _fixed_record_loop(rng, n, width):
+    """Row-at-a-time reference for the bulk fixed-record generator."""
+    out = np.full((n, width), ord("0"), np.uint8)
+    doc = rng.integers(0, 10000, n)
+    item = rng.integers(0, 100, n)
+    for i in range(n):
+        s = f"{2024:04d}{int(doc[i]):08d}{int(item[i]):04d}{i:010d}".encode()
+        out[i, : len(s)] = np.frombuffer(s[:width], np.uint8)
+    keys = sorted(set(bytes(r) for r in out))
+    rng.shuffle(keys)
+    return KF.keys_to_words(keys)
+
+
+@pytest.mark.parametrize("name,n", [("INDBTAB", 1), ("INDBTAB", 2000), ("Part", 777)])
+def test_fixed_record_dataset_matches_loop(name, n):
+    """The bulk INDBTAB/Part generator is byte-identical to the row loop."""
+    from dataclasses import replace
+
+    from repro.configs.paper_index import DATASETS
+    from repro.data.synthetic import dataset_keys
+
+    cfg = replace(DATASETS[name], n_keys=n)
+    got = dataset_keys(cfg, seed=3)
+    want = _fixed_record_loop(np.random.default_rng(3), n, cfg.key_bytes)
+    np.testing.assert_array_equal(got.words, want.words)
+    np.testing.assert_array_equal(got.lengths, want.lengths)
+    np.testing.assert_array_equal(got.rids, want.rids)

@@ -11,6 +11,11 @@ compiled-program count stays fixed (a second call in the same bucket, at a
 assertion is strong).
 """
 
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -176,6 +181,38 @@ def test_trace_counter_counts_traces_not_calls():
     assert cache.stats()["traces"] == 1
     f(jnp.zeros((8,)))  # new shape: one more trace
     assert cache.stats()["traces"] == 2
+
+
+_CACHE_PROBE = """
+import sys
+import jax, jax.numpy as jnp
+from repro.core.plancache import enable_persistent_cache
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+print(enable_persistent_cache(sys.argv[1]))
+jax.jit(lambda x: x * 3 + 1)(jnp.arange(8)).block_until_ready()
+"""
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_persistent_cache_location(tmp_path, from_env):
+    """The compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else
+    to the fixed ``<root>/.jax_cache`` (which git ignores) — never both.
+    A subprocess, because the cache directory is process-global."""
+    repo = Path(__file__).resolve().parents[1]
+    root, env_dir = tmp_path / "checkout", tmp_path / "env_cache"
+    root.mkdir()
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(repo / "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE, str(root)],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr
+    want, other = (env_dir, root / ".jax_cache") if from_env else (root / ".jax_cache", env_dir)
+    assert r.stdout.strip().splitlines()[-1] == str(want)
+    assert any(want.iterdir())
+    assert not other.exists()
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
 
 
 # ---------------------------------------------------------------------------

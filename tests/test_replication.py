@@ -78,17 +78,24 @@ def test_merge_sorted_matches_sort_every_backend(rng, na, nb):
         np.testing.assert_array_equal(np.asarray(mr), np.asarray(ref_r), err_msg=name)
 
 
-def test_merge_kernel_matches_numpy_ref(rng):
-    from repro.kernels.merge import merge_ranks
-    from repro.kernels.merge.ref import merge_ranks_ref
-
+def test_merge_sorted_pallas_matches_jnp(rng):
+    """The pallas merge is the XLA merge: bucket-padded ladder-form calls
+    (``n_valid`` + ``keep_padded``) give the jnp backend's bytes."""
     w, mask = 2, 0x3F
-    ks, rs = _sorted_run(rng, 200, w, mask, np.arange(200, dtype=np.uint32))
-    kq = rng.integers(0, 2**32, size=(77, w), dtype=np.uint32) & np.uint32(mask)
-    rq = np.arange(200, 277, dtype=np.uint32)
-    got = np.asarray(merge_ranks(jnp.asarray(kq), jnp.asarray(rq), ks, rs))
-    want = merge_ranks_ref(kq, rq, np.asarray(ks), np.asarray(rs))
-    np.testing.assert_array_equal(got, want)
+    ka, ra = _sorted_run(rng, 200, w, mask, np.arange(200, dtype=np.uint32))
+    kb, rb = _sorted_run(rng, 77, w, mask, np.arange(200, 277, dtype=np.uint32))
+    pallas = get_backend("pallas", interpret=True)
+    assert pallas.last_info["merge"] == "xla"
+    outs = [
+        be.merge_sorted(
+            jnp.pad(ka, ((0, 56), (0, 0))), jnp.pad(ra, (0, 56)),
+            jnp.pad(kb, ((0, 51), (0, 0))), jnp.pad(rb, (0, 51)),
+            n_valid_a=200, n_valid_b=77, keep_padded=True,
+        )
+        for be in (pallas, get_backend("jnp"))
+    ]
+    for got, want in zip(outs[0], outs[1]):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_merge_words_keyed_is_permutation(rng):

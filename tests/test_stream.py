@@ -449,6 +449,32 @@ def test_watermark_noop_fast_path(rng):
 # ---------------------------------------------------------------------------
 
 
+def _check_catchup(tmp_path, seed, n_batches, lag_from):
+    rng = np.random.default_rng(seed)
+    ckpt = tmp_path / f"ckpt_{seed}_{n_batches}_{lag_from}"
+    t = QueueTransport()
+    prim = StreamPrimary(t, _keyset(rng, 300), ckpt_dir=str(ckpt),
+                         max_lag_batches=lag_from)
+    tail = StreamReplica(t)
+    lagger = StreamReplica(t)
+    tail.poll()
+    for _ in range(n_batches):
+        prim.publish(_random_batch(
+            rng, prim,
+            n_ins=int(rng.integers(0, 30)),
+            n_del=int(rng.integers(0, 10)),
+        ))
+        tail.poll()
+    lagger.poll()
+    if prim.stats["ckpt_step"] >= 2:
+        # retention keeps one checkpoint cycle: a second checkpoint
+        # truncated the lagger's tail, forcing the catch-up path
+        assert lagger.stats["n_catchups"] >= 1
+    _assert_replica_state_identical(tail.replica, prim.replica)
+    _assert_replica_state_identical(lagger.replica, prim.replica)
+    return lagger
+
+
 def test_catchup_equals_never_lagged_hypothesis(tmp_path):
     pytest.importorskip("hypothesis")  # property tests need the dev extra
     from hypothesis import given, settings
@@ -458,30 +484,20 @@ def test_catchup_equals_never_lagged_hypothesis(tmp_path):
     @given(seed=st.integers(0, 10**6), n_batches=st.integers(3, 6),
            lag_from=st.integers(1, 2))
     def check(seed, n_batches, lag_from):
-        rng = np.random.default_rng(seed)
-        ckpt = tmp_path / f"ckpt_{seed}_{n_batches}_{lag_from}"
-        t = QueueTransport()
-        prim = StreamPrimary(t, _keyset(rng, 300), ckpt_dir=str(ckpt),
-                             max_lag_batches=lag_from)
-        tail = StreamReplica(t)
-        lagger = StreamReplica(t)
-        tail.poll()
-        for _ in range(n_batches):
-            prim.publish(_random_batch(
-                rng, prim,
-                n_ins=int(rng.integers(0, 30)),
-                n_del=int(rng.integers(0, 10)),
-            ))
-            tail.poll()
-        lagger.poll()
-        if prim.stats["ckpt_step"] >= 2:
-            # retention keeps one checkpoint cycle: a second checkpoint
-            # truncated the lagger's tail, forcing the catch-up path
-            assert lagger.stats["n_catchups"] >= 1
-        _assert_replica_state_identical(tail.replica, prim.replica)
-        _assert_replica_state_identical(lagger.replica, prim.replica)
+        _check_catchup(tmp_path, seed, n_batches, lag_from)
 
     check()
+
+
+@pytest.mark.parametrize("seed,n_batches", [(149529, 3), (719470, 4), (97192, 3)])
+def test_replay_from_origin_keeps_stale_dbits(tmp_path, seed, n_batches):
+    """A replica that first polls after several batches, with every frame
+    still retained, replays from the stream origin instead of a
+    checkpoint.  It must bring up from the genesis batch and fold the
+    rest, so the D-bits of deleted keys stay set as on the primary (each
+    seed here deletes a key whose D-bit no surviving pair has)."""
+    lagger = _check_catchup(tmp_path, seed, n_batches, lag_from=2)
+    assert lagger.stats["n_catchups"] == 0
 
 
 # ---------------------------------------------------------------------------

@@ -316,8 +316,7 @@ def test_gradient_compression_subprocess():
         mesh = make_mesh((8,), ("pod",))
         g = {"w": jnp.arange(8*32, dtype=jnp.float32).reshape(8, 32) / 100.0}
         ef = ef_init(g)
-        from repro.compat import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(compressed_allreduce_grads, axis_name="pod"),
             mesh=mesh, in_specs=(P("pod"), P("pod")),
             out_specs=(P(), P("pod")))
