@@ -14,6 +14,7 @@ from . import (
     reconstruct,
     snapshot,
     sortkeys,
+    spans,
 )
 
 __all__ = [
@@ -28,4 +29,5 @@ __all__ = [
     "reconstruct",
     "snapshot",
     "sortkeys",
+    "spans",
 ]

@@ -37,6 +37,7 @@ from .dbits import (
     lex_compare_le,
 )
 from .metadata import DSMeta
+from .spans import span
 
 __all__ = [
     "BTreeConfig",
@@ -550,13 +551,14 @@ def lookup_batch_planned(
     queries = jnp.asarray(queries, jnp.uint32)
     q, w = int(queries.shape[0]), int(queries.shape[1])
     b = plancache.bucket_for("lookup", q)
-    prog = cache.program(
-        ("lookup", backend_name, b, w) + program_key_extra,
-        lambda: _lookup_program(cache, leaf_match_fn),
-    )
-    qp = plancache.pad_tail(queries, b, 0xFFFFFFFF)
-    found, rid = prog(tree, qp, np.uint32(q))
-    return found[:q], rid[:q]
+    with span("lookup"):
+        prog = cache.program(
+            ("lookup", backend_name, b, w) + program_key_extra,
+            lambda: _lookup_program(cache, leaf_match_fn),
+        )
+        qp = plancache.pad_tail(queries, b, 0xFFFFFFFF)
+        found, rid = prog(tree, qp, np.uint32(q))
+        return found[:q], rid[:q]
 
 
 # ---------------------------------------------------------------------------
@@ -725,12 +727,13 @@ def lookup_many_planned(
     nv_full = np.zeros((t_cap,), np.uint32)
     nv_full[:t_q] = np.minimum(nv, q)
     b = plancache.bucket_for("lookup_many", q)
-    prog = cache.program(
-        ("lookup_many", backend_name, t_cap, b, w, tree_geometry(stacked))
-        + program_key_extra,
-        lambda: _lookup_many_program(cache, leaf_match_many_fn),
-    )
-    qp = plancache.pad_tail(queries, b, 0xFFFFFFFF, axis=1)
-    qp = plancache.pad_tail(qp, t_cap, 0xFFFFFFFF, axis=0)
-    found, rid = prog(stacked, qp, jnp.asarray(nv_full))
-    return found[:t_q, :q], rid[:t_q, :q]
+    with span("lookup"):
+        prog = cache.program(
+            ("lookup_many", backend_name, t_cap, b, w, tree_geometry(stacked))
+            + program_key_extra,
+            lambda: _lookup_many_program(cache, leaf_match_many_fn),
+        )
+        qp = plancache.pad_tail(queries, b, 0xFFFFFFFF, axis=1)
+        qp = plancache.pad_tail(qp, t_cap, 0xFFFFFFFF, axis=0)
+        found, rid = prog(stacked, qp, jnp.asarray(nv_full))
+        return found[:t_q, :q], rid[:t_q, :q]

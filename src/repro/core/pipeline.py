@@ -57,6 +57,7 @@ from .btree import BTree, BTreeConfig
 from .keyformat import KeySet
 from .metadata import DSMeta, meta_from_keys
 from .sortkeys import word_comparison_counts
+from .spans import span, spanned
 
 __all__ = [
     "ReconstructionResult",
@@ -261,21 +262,23 @@ class ReconstructionPipeline:
         self.chunk_plan = plan
         return plan
 
-    def _stage(self, sync: bool, fn, *args):
-        """Run one stage; barrier on its outputs only when ``sync``.
+    def _stage(self, name: str, sync: bool, fn, *args):
+        """Run one stage under the span ``repro.rebuild.<name>``; barrier
+        on its outputs only when ``sync``.
 
         Async mode leaves the outputs as in-flight device arrays — the next
-        stage's dispatch overlaps their compute — so the returned wall is
-        dispatch time, not execution time."""
-        t0 = time.perf_counter()
-        out = fn(*args)
-        if sync:
-            out = jax.tree_util.tree_map(
-                lambda x: x.block_until_ready()
-                if hasattr(x, "block_until_ready") else x,
-                out,
-            )
-        return out, time.perf_counter() - t0
+        stage's dispatch overlaps their compute — so the returned wall (and
+        the span) is dispatch time, not execution time."""
+        with span("rebuild." + name):
+            t0 = time.perf_counter()
+            out = fn(*args)
+            if sync:
+                out = jax.tree_util.tree_map(
+                    lambda x: x.block_until_ready()
+                    if hasattr(x, "block_until_ready") else x,
+                    out,
+                )
+            return out, time.perf_counter() - t0
 
     @staticmethod
     def _sync(*arrays) -> float:
@@ -362,6 +365,7 @@ class ReconstructionPipeline:
         return ks, rs
 
     # ---------------------------------------------------------------- run
+    @spanned("rebuild")
     def run(
         self,
         keyset: KeySet,
@@ -393,18 +397,20 @@ class ReconstructionPipeline:
         sync = (stage_timings if stage_timings is not None
                 else not self.async_dispatch)
         n = keyset.n
-        rids = jnp.asarray(keyset.rids, jnp.uint32)
-        lengths = jnp.asarray(keyset.lengths, jnp.int32)
-        # enter the bucket world once: pad the full keys to the sort bucket
-        # against cached constants (one dynamic_update_slice, no per-call
-        # concatenate/fill) and take the cached iota as the row ids.  Pad
-        # lane *content* is irrelevant from here on — every cached program
-        # renormalizes its pads from the dynamic valid-count operand.
         b = plancache.bucket_for("sort", n)
-        words_dev = plancache.pad_tail(
-            jnp.asarray(keyset.words, jnp.uint32), b, 0xFFFFFFFF
-        )
-        rows_dev = plancache.iota_u32(b)
+        with span("rebuild.upload"):  # the host's part; adds no barrier
+            rids = jnp.asarray(keyset.rids, jnp.uint32)
+            lengths = jnp.asarray(keyset.lengths, jnp.int32)
+            # enter the bucket world once: pad the full keys to the sort
+            # bucket against cached constants (one dynamic_update_slice, no
+            # per-call concatenate/fill) and take the cached iota as the row
+            # ids.  Pad lane *content* is irrelevant from here on — every
+            # cached program renormalizes its pads from the dynamic
+            # valid-count operand.
+            words_dev = plancache.pad_tail(
+                jnp.asarray(keyset.words, jnp.uint32), b, 0xFFFFFFFF
+            )
+            rows_dev = plancache.iota_u32(b)
 
         t_meta = 0.0
         if full_keys:
@@ -437,18 +443,20 @@ class ReconstructionPipeline:
             if full_keys:
                 comp, t_extract = words_dev, 0.0
             else:
-                comp, t_extract = self._stage(sync, self.extract, words_dev, plan)
+                comp, t_extract = self._stage(
+                    "extract", sync, self.extract, words_dev, plan
+                )
             # chunk sorts consume their key slices — strict sub-slices are
             # fresh buffers even when comp is words_dev, but a single
             # clamped full slice *is* comp, so full_keys then opts out
             donate_sorts = donate and (not full_keys or chunks > 1)
             (comp_sorted_p, row_sorted_p), t_sort = self._stage(
-                sync, lambda: self._sort_chunked(comp, n, b, donate_sorts)
+                "sort", sync, lambda: self._sort_chunked(comp, n, b, donate_sorts)
             )
         elif full_keys:
             t_extract = 0.0
             (comp_sorted_p, row_sorted_p), t_sort = self._stage(
-                sync,
+                "sort", sync,
                 lambda: self.sort(words_dev, rows_dev, n_valid=n,
                                   keep_padded=True),
             )
@@ -456,16 +464,18 @@ class ReconstructionPipeline:
             fused_used = True
             t_extract = 0.0
             (comp_sorted_p, row_sorted_p), t_sort = self._stage(
-                sync,
+                "sort", sync,
                 lambda: self.backend.fused_extract_sort(
                     words_dev, plan, rows_dev, n_valid=n, keep_padded=True
                 ),
             )
         else:
-            comp, t_extract = self._stage(sync, self.extract, words_dev, plan)
+            comp, t_extract = self._stage(
+                "extract", sync, self.extract, words_dev, plan
+            )
             # comp is the extract output and dies with the sort
             (comp_sorted_p, row_sorted_p), t_sort = self._stage(
-                sync,
+                "sort", sync,
                 lambda: self.sort(comp, rows_dev, n_valid=n, keep_padded=True,
                                   donate=donate),
             )
@@ -479,7 +489,7 @@ class ReconstructionPipeline:
         # -- build may consume row_sorted_p (its scratch) once the result
         # -- slices above are dispatched ------------------------------------
         tree, t_build = self._stage(
-            sync,
+            "build", sync,
             lambda: self.build(
                 comp_sorted_p, row_sorted_p, meta, words_dev, lengths, rids,
                 n_valid=n, donate=donate_results,
@@ -491,12 +501,13 @@ class ReconstructionPipeline:
         t_refresh = 0.0
         new_meta = meta
         if not full_keys:
-            t0 = time.perf_counter()
-            new_meta = self.refresh_meta(
-                comp_sorted_p, meta, keyset.words[0], n_valid=n,
-                donate=donate_results,
-            )
-            t_refresh = time.perf_counter() - t0
+            with span("rebuild.refresh"):
+                t0 = time.perf_counter()
+                new_meta = self.refresh_meta(
+                    comp_sorted_p, meta, keyset.words[0], n_valid=n,
+                    donate=donate_results,
+                )
+                t_refresh = time.perf_counter() - t0
 
         t_sync = 0.0 if sync else self._sync(comp_sorted, row_sorted, rid_sorted)
         timings = {
@@ -534,6 +545,7 @@ class ReconstructionPipeline:
         return res
 
     # -------------------------------------------------- incremental (delta)
+    @spanned("rebuild")
     def run_incremental(
         self,
         prev: ReconstructionResult,
@@ -650,7 +662,7 @@ class ReconstructionPipeline:
             base_rows = new_row[prev.row_sorted][keep_sorted].astype(jnp.uint32)
             return base_comp, base_rows
 
-        (base_comp, base_rows), t_filter = self._stage(sync, _filter)
+        (base_comp, base_rows), t_filter = self._stage("filter", sync, _filter)
         n_kept = int(base_comp.shape[0])
 
         # -- extract + sort only the delta.  The delta's compressed keys
@@ -661,10 +673,10 @@ class ReconstructionPipeline:
         if n_delta:
             delta_words = jnp.asarray(delta_keyset.words, jnp.uint32)
             comp_delta, t_extract = self._stage(
-                sync, self.extract, delta_words, plan
+                "extract", sync, self.extract, delta_words, plan
             )
             (comp_delta_sorted, rows_delta), t_sort = self._stage(
-                sync,
+                "sort", sync,
                 lambda: self.sort(
                     comp_delta, jnp.arange(n_delta, dtype=jnp.uint32),
                     donate=self.donate,
@@ -679,7 +691,7 @@ class ReconstructionPipeline:
 
         # -- merge the runs (the backend op) -------------------------------
         (comp_sorted, row_sorted), t_merge = self._stage(
-            sync, self.backend.merge_sorted,
+            "merge", sync, self.backend.merge_sorted,
             base_comp, base_rows, comp_delta_sorted, rows_delta,
         )
         row_sorted = jnp.asarray(row_sorted, jnp.uint32)
@@ -691,12 +703,13 @@ class ReconstructionPipeline:
         lengths = jnp.asarray(folded.lengths, jnp.int32)
         rids = jnp.asarray(folded.rids, jnp.uint32)
         tree, t_build = self._stage(
-            sync, self.build, comp_sorted, row_sorted, meta, words, lengths,
-            rids,
+            "build", sync, self.build, comp_sorted, row_sorted, meta, words,
+            lengths, rids,
         )
-        t0 = time.perf_counter()
-        new_meta = self.refresh_meta(comp_sorted, meta, folded.words[0])
-        t_refresh = time.perf_counter() - t0
+        with span("rebuild.refresh"):
+            t0 = time.perf_counter()
+            new_meta = self.refresh_meta(comp_sorted, meta, folded.words[0])
+            t_refresh = time.perf_counter() - t0
 
         t_sync = 0.0 if sync else self._sync(comp_sorted, row_sorted, rid_sorted)
         timings = {
@@ -732,6 +745,7 @@ class ReconstructionPipeline:
             publish_to.publish(res)
         return res, folded
 
+    @spanned("rebuild.stats")  # its float(...) calls wait for the device
     def _stats(self, keyset, meta, comp_sorted, row_sorted, tree, fused_used):
         full_bits = keyset.n_bits
         # wcc over the *row*-permuted full keys: row_sorted indexes rows of

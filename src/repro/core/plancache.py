@@ -286,7 +286,11 @@ class PlanCache:
         while JAX traces, so ``traces`` counts compilations, not calls.
         When called from inside a :meth:`program` builder the tracings are
         also attributed to that program's op family in :attr:`per_op`
-        (``"_unkeyed"`` otherwise)."""
+        (``"_unkeyed"`` otherwise).
+
+        The program is named after its op family (``fn``'s own name when
+        unkeyed), so the compiled module is ``jit_sort``, ``jit_merge``,
+        ``jit_lookup``, ... in the lowered text and the device trace."""
         op = self._building_op or "_unkeyed"
 
         def traced(*args, **kwargs):
@@ -295,6 +299,8 @@ class PlanCache:
                 self._per_op(op)["traces"] += 1
             return fn(*args, **kwargs)
 
+        traced.__name__ = traced.__qualname__ = (
+            op if self._building_op else getattr(fn, "__name__", op))
         jitted = jax.jit(traced, **jit_kwargs)
         if not jit_kwargs.get("donate_argnums"):
             return jitted

@@ -78,6 +78,8 @@ from typing import TYPE_CHECKING, Iterator
 
 import numpy as np
 
+from .spans import spanned
+
 if TYPE_CHECKING:  # the pipeline imports this module; keep the cycle lazy
     from .btree import BTree
     from .metadata import DSMeta
@@ -296,6 +298,7 @@ class SnapshotCell:
             return sorted(e for e, c in self._pins.items() if c > 0)
 
     # ------------------------------------------------------------- publish
+    @spanned("snapshot.publish")
     def publish(
         self, result: "ReconstructionResult", epoch: int | None = None
     ) -> IndexSnapshot:
@@ -382,6 +385,7 @@ class SnapshotCell:
         self.park_wait_s += time.perf_counter() - t0
 
     # ------------------------------------------------------------- readers
+    @spanned("snapshot.pin")
     def acquire(self) -> SnapshotPin:
         """Pin the current snapshot; returns a one-shot :class:`SnapshotPin`.
 
