@@ -9,8 +9,9 @@ merge ladder dropping runs as they fold) and ``async_dispatch=True`` (one
 end-of-run sync instead of per-stage barriers), so each cell now reports
 the per-stage-synced warm wall *and* the async warm wall plus their
 ratio.  A forced-chunked cell (``scale/<backend>/262144/chunked``) runs
-the cascade below the production threshold so CI can gate the chunked
-path at fast-suite sizes; the full sweep additionally calibrates
+the cascade with an explicit threshold (by default the pipeline sorts a
+bucket whole) so CI can gate the chunked path at fast-suite sizes; the
+full sweep additionally calibrates
 ``chunk_size``/``chunk_threshold`` per backend with
 ``tune_chunking`` (probes compile into a scoped throwaway cache, so the
 serving cold walls stay honest).
@@ -208,9 +209,8 @@ def run(
                 row["chunk_plan"] = dataclasses.asdict(pipe.chunk_plan)
             rows.append(row)
 
-        # the forced-chunked cell: the cascade below its production
-        # threshold, so the fast suite (and CI) always exercises and
-        # gates the chunked path
+        # the forced-chunked cell: an explicit threshold, so the fast
+        # suite (and CI) always exercises and gates the chunked path
         if FORCED_CHUNK_N in sizes:
             forced = ReconstructionPipeline(
                 backend=name, donate=True, async_dispatch=True,

@@ -14,8 +14,9 @@ drawn from ``--seed`` — through the entry points a user calls:
                   sort (the pipeline re-sorts its output, so only this
                   check can see a wrong network).
 3. ``recover``:   ``ReconstructionPipeline(backend="pallas",
-                  backend_opts={"interpret": False})`` with default
-                  chunking rebuilds the index and publishes it to a
+                  backend_opts={"interpret": False})`` with the default
+                  sort (one program over the whole bucket) rebuilds the
+                  index and publishes it to a
                   ``SnapshotCell``; then the same rebuild once more, warm.
 4. ``lookups``:   4,096 point lookups (half present, half absent) from the
                   pinned epoch through the backend ``lookup`` op.

@@ -154,14 +154,14 @@ class ReconstructionPipeline:
     backend_opts:  forwarded to the backend constructor when ``backend`` is
                    a name (e.g. ``{"interpret": False}`` for pallas on TPU,
                    ``{"mesh": mesh, "capacity_factor": 2.0}`` for distributed).
-    chunk_threshold: key counts above this take the chunked large-N sort
-                   path: the keyset splits into ``chunk_size``-aligned
-                   chunks, each sorted through the (small-bucket) cached
-                   sort programs, folded with a binary-counter ladder of
-                   cached merges.  Keeps million-key rebuilds on the same
-                   handful of compiled programs the serving sizes already
-                   trace.
-    chunk_size:    chunk length for the large-N path (power of two).
+    chunk_threshold: ``None`` (the default) sorts a rebuild's whole padded
+                   bucket in one cached backend ``sort`` program.  An
+                   integer instead takes the chunked path for every key
+                   count above it: the keyset splits into ``chunk_size``
+                   chunks, each sorted through the cached sort program and
+                   folded with a binary-counter ladder of cached merges.
+    chunk_size:    chunk length for an explicit ``chunk_threshold`` (power
+                   of two).
     async_dispatch: skip the per-stage ``block_until_ready`` barriers and
                    sync once at the end of ``run``/``run_incremental``.
                    JAX async dispatch then overlaps host-side program
@@ -185,7 +185,8 @@ class ReconstructionPipeline:
     auto_tune_chunks: lazily calibrate ``chunk_size``/``chunk_threshold``
                    from measured per-bucket sort and merge program costs
                    (:func:`repro.core.plancache.tune_chunking`) the first
-                   time a run crosses the current threshold; the measured
+                   time a run crosses an explicit threshold (with
+                   ``chunk_threshold=None`` no run chunks); the measured
                    :class:`~repro.core.plancache.ChunkPlan` persists on
                    the pipeline and is surfaced in ``stats``.
     """
@@ -196,7 +197,7 @@ class ReconstructionPipeline:
         config: BTreeConfig = BTreeConfig(),
         fused: bool = False,
         backend_opts: dict | None = None,
-        chunk_threshold: int = 1 << 19,
+        chunk_threshold: int | None = None,
         chunk_size: int = 1 << 17,
         async_dispatch: bool = False,
         donate: bool = False,
@@ -208,7 +209,8 @@ class ReconstructionPipeline:
             self.backend = get_backend(backend, **(backend_opts or {}))
         self.config = config
         self.fused = bool(fused)
-        self.chunk_threshold = int(chunk_threshold)
+        self.chunk_threshold = (None if chunk_threshold is None
+                                else int(chunk_threshold))
         self.chunk_size = int(chunk_size)
         self.async_dispatch = bool(async_dispatch)
         self.donate = bool(donate)
@@ -421,9 +423,10 @@ class ReconstructionPipeline:
             t_meta = time.perf_counter() - t0
         plan = meta.plan()
 
-        if (self.auto_tune_chunks and self.chunk_plan is None
-                and n > self.chunk_threshold):
+        chunked = self.chunk_threshold is not None and n > self.chunk_threshold
+        if chunked and self.auto_tune_chunks and self.chunk_plan is None:
             self.tune_chunking()
+            chunked = n > self.chunk_threshold
 
         # Donation guards: ``words_dev`` is never donated (the build stage
         # reads it after the sort on the full-keys and fused paths, and the
@@ -436,8 +439,8 @@ class ReconstructionPipeline:
         # -- extract / sort (backend-dispatched, optionally fused) ---------
         fused_used = False
         chunks = 0
-        if n > self.chunk_threshold:
-            # large-N path: extraction stays one bucket-shaped program; the
+        if chunked:
+            # the ladder: extraction stays one bucket-shaped program; the
             # sort splits into chunk-bucket programs + a merge ladder
             chunks = -(-n // self.chunk_size)
             if full_keys:

@@ -1,10 +1,12 @@
 """Chunked large-N sort path: byte-identity vs the monolithic sort on
 every backend, boundary sizes (2^k - 1, 2^k, 2^k + 1), cascade retrace
-stability, and the run_many batched grouping under per-op floors.
+stability, the run_many batched grouping under per-op floors, and the
+default that sorts a whole bucket in one program.
 
-The chunk sizes here are scaled far below the production defaults
-(``chunk_threshold=1<<19``) so the cascade runs in test time; the code
-path is identical — only the constants differ.
+By default (``chunk_threshold=None``) a rebuild sorts its whole bucket in
+one program; the ladder runs only under an explicit ``chunk_threshold``,
+which the tests here scale far below production sizes so the cascade
+runs in test time.
 """
 
 import numpy as np
@@ -240,3 +242,54 @@ def test_auto_tune_triggers_lazily(rng):
     np.testing.assert_array_equal(
         np.asarray(res1.comp_sorted), np.asarray(ref.comp_sorted)
     )
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas", "distributed"])
+@pytest.mark.parametrize("n", [2**12 - 1, 2**12 + 1])
+def test_default_pipeline_sorts_whole_bucket(rng, backend, n):
+    """A default-constructed pipeline sorts the whole bucket in one sort
+    program and compiles no merge program, byte-identical to the ladder."""
+    ks = _keyset(rng, n)
+    meta = meta_from_keys(ks.words)
+    with plancache.scoped_cache() as cache:
+        res = ReconstructionPipeline(backend=backend).run(ks, meta=meta)
+        per_op = cache.stats()["per_op"]
+    assert res.stats["chunked"] == 0
+    assert res.stats["chunk_threshold"] is None
+    assert "cascade_merges" not in res.stats
+    assert "merge" not in per_op
+    ladder = ReconstructionPipeline(
+        backend=backend, chunk_threshold=2048, chunk_size=1024
+    ).run(ks, meta=meta)
+    assert ladder.stats["cascade_merges"] == ladder.stats["chunked"] - 1 > 0
+    _assert_results_equal(res, ladder)
+
+
+@pytest.mark.parametrize("threshold, chunk_size", [(1024, 512), (2048, 256)])
+def test_explicit_chunk_threshold_takes_the_ladder(rng, threshold, chunk_size):
+    """An explicit ``chunk_threshold`` keeps the ladder past it, in
+    ``chunk_size`` chunks, and one sort at or below it."""
+    n = 3000
+    ks = _keyset(rng, n)
+    meta = meta_from_keys(ks.words)
+    pipe = ReconstructionPipeline(
+        "jnp", chunk_threshold=threshold, chunk_size=chunk_size
+    )
+    res = pipe.run(ks, meta=meta)
+    assert res.stats["chunked"] == -(-n // chunk_size)
+    assert res.stats["chunk_size"] == chunk_size
+    assert res.stats["cascade_merges"] == res.stats["chunked"] - 1
+    small = _keyset(rng, threshold)
+    assert pipe.run(small).stats["chunked"] == 0
+    _assert_results_equal(ReconstructionPipeline("jnp").run(ks, meta=meta), res)
+
+
+def test_auto_tune_needs_an_explicit_threshold(rng):
+    """With the default ``chunk_threshold=None`` no run chunks, so
+    ``auto_tune_chunks`` never calibrates."""
+    pipe = ReconstructionPipeline("jnp", auto_tune_chunks=True)
+    pipe.tune_chunking = lambda **kw: pytest.fail("tuned without a ladder")
+    res = pipe.run(_keyset(rng, 2048))
+    assert pipe.chunk_plan is None
+    assert res.stats["chunked"] == 0
+    assert res.stats["chunk_tuned"] is False
