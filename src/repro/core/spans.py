@@ -18,7 +18,14 @@ Span names (``repro.`` + name):
 - ``snapshot.publish`` and ``snapshot.pin``: ``SnapshotCell.publish``
   and ``SnapshotCell.acquire``;
 - ``lookup``: ``btree.lookup_batch_planned`` and ``lookup_many_planned``,
-  from the program fetch to the return of the device arrays.
+  from the program fetch to the return of the device arrays;
+- ``stream.poll``: the whole ``StreamReplica.poll``, with
+  ``stream.decode`` around each frame's unpack, CRC32C check and decode
+  (their sum is the poll's ``decode`` seconds);
+- ``replica.apply``: the whole ``Replica.apply``, with ``replica.fold``
+  (the log replayed against the base rows) and ``replica.insert_rule``
+  (the §4.3 metadata upkeep) inside it, the intervals of its ``fold`` and
+  ``insert_rule`` timings, and the ``rebuild`` it runs.
 
 Device time is attributed by program name instead: every plan-cache
 program is named after its op family (``jit_sort``, ``jit_merge``, ...;

@@ -21,6 +21,7 @@ Delta-internal adjacency is covered by the delta's own D-bitmap.
 
 from __future__ import annotations
 
+import time
 from dataclasses import replace
 
 import jax.numpy as jnp
@@ -38,6 +39,7 @@ from repro.core.keyformat import KeySet  # noqa: F401  (public API type)
 from repro.core.metadata import DSMeta, shed_or_pin
 from repro.core.pipeline import ReconstructionPipeline, ReconstructionResult
 from repro.core.snapshot import SnapshotCell
+from repro.core.spans import span, spanned
 
 from .log import ChangeLog
 
@@ -183,6 +185,7 @@ class Replica:
         """
         return self.apply(ChangeLog.concat(logs))
 
+    @spanned("replica.apply")
     def apply(self, log: ChangeLog) -> dict:
         """Fold one log batch into the standing index.
 
@@ -194,16 +197,27 @@ class Replica:
         batches short-circuit through the pipeline's no-op fast path and
         only advance the watermark).  Returns apply stats: which path ran
         (``incremental`` / ``fallback`` / ``noop``), churn counts, shed
-        policy state, the new ``applied_lsn``, and per-stage timings.
+        policy state, the new ``applied_lsn``, and per-stage timings: the
+        pipeline's, plus ``fold`` (the log replayed against the base rows
+        on the host) and ``insert_rule`` (the §4.3 metadata upkeep, which
+        waits for the device), in seconds.
         """
         if log.n_words != self.keyset.n_words:
             raise ValueError(
                 f"log key width {log.n_words} != index width {self.keyset.n_words}"
             )
-        keep_rows, delta = log.fold_keyset(self.keyset)
+        with span("replica.fold"):
+            t0 = time.perf_counter()
+            keep_rows, delta = log.fold_keyset(self.keyset)
+            t_fold = time.perf_counter() - t0
         n_delta = 0 if delta is None else delta.n
         n_deleted = 0 if keep_rows is None else int(self.keyset.n - keep_rows.sum())
-        meta = self._insert_rule(delta.words) if n_delta else self._meta
+        meta, t_rule = self._meta, 0.0
+        if n_delta:
+            with span("replica.insert_rule"):
+                t0 = time.perf_counter()
+                meta = self._insert_rule(delta.words)
+                t_rule = time.perf_counter() - t0
 
         res, folded = self.pipeline.run_incremental(
             self.result, self.keyset, delta, keep_rows=keep_rows, meta=meta,
@@ -227,7 +241,7 @@ class Replica:
             "shed_bits": shed,
             "deletes_since_shed": self._deletes_since_shed,
             "applied_lsn": self.applied_lsn,
-            "timings": dict(res.timings),
+            "timings": {**res.timings, "fold": t_fold, "insert_rule": t_rule},
         }
 
     # ------------------------------------------------------- shed adoption

@@ -49,12 +49,14 @@ pre-watermark snapshot — never a torn mixture of two reconstructions.
 from __future__ import annotations
 
 import io
+import time
 from dataclasses import dataclass
 
 import numpy as np
 
 from repro.core.keyformat import KeySet
 from repro.core.metadata import DSMeta
+from repro.core.spans import span, spanned
 
 from .log import ChangeLog
 from .replica import Replica
@@ -345,6 +347,11 @@ class StreamPrimary:
                       pending, then ship them as one batch whose size tags
                       a plan-cache bucket; ``None`` ships every publish
                       immediately.  ``flush()`` forces the buffer out.
+    start_lsn:        LSN the first published log starts at.  A tracked
+                      primary starts at 0 with its genesis batch; a
+                      fire-and-forget publisher whose consumers already
+                      hold the rows through ``start_lsn - 1`` (brought up
+                      by ``StreamReplica.from_table``) starts past them.
     """
 
     def __init__(
@@ -359,9 +366,13 @@ class StreamPrimary:
         ckpt_dir: "str | None" = None,
         max_lag_batches: int | None = None,
         coalesce_min: int | None = None,
+        start_lsn: int = 0,
     ) -> None:
         if keyset is None and n_words is None:
             raise ValueError("need a base keyset or an explicit n_words")
+        if keyset is not None and start_lsn != 0:
+            raise ValueError("a tracked primary's stream starts at its genesis "
+                             "batch, LSN 0")
         if max_lag_batches is not None and (keyset is None or ckpt_dir is None):
             raise BackpressureError(
                 "max_lag_batches needs a tracked index (keyset) and a "
@@ -376,7 +387,7 @@ class StreamPrimary:
         self.coalesce_min = coalesce_min
         self.n_words = int(keyset.n_words if keyset is not None else n_words)
         self._pending: list[ChangeLog] = []
-        self._next_lsn = 0
+        self._next_lsn = int(start_lsn)
         self._wire_seq = 0
         self._ckpt_step = 0
         self._prev_ckpt_pos: int | None = None
@@ -587,8 +598,10 @@ class StreamReplica:
 
     Holds a cursor into the transport and an inner :class:`Replica` (built
     lazily: from the genesis batch, or from a checkpoint frame during
-    catch-up).  ``poll()`` drains available frames and folds all pending
-    batches through one watermark-triggered incremental rebuild.
+    catch-up; or at once from a table already in memory, see
+    :meth:`from_table`).  ``poll()`` drains available frames and folds
+    all pending batches through one watermark-triggered incremental
+    rebuild.
 
     The LSN watermark check makes delivery faults safe: duplicate batches
     are skipped, overlapping batches are sliced to the unseen suffix, and
@@ -640,6 +653,37 @@ class StreamReplica:
         self.n_reorder_heals = 0
         self.n_resyncs = 0
 
+    @classmethod
+    def from_table(
+        cls,
+        transport: Transport,
+        keyset: KeySet,
+        applied_lsn: int,
+        *,
+        meta: DSMeta | None = None,
+        backend: str = "jnp",
+        backend_opts: dict | None = None,
+    ) -> "StreamReplica":
+        """Bring a replica up from a table already in memory.
+
+        The third bring-up, beside the genesis batch and a checkpoint
+        frame: ``keyset`` holds the rows current through ``applied_lsn``
+        (as the genesis batch's fold would give them) and ``meta`` their
+        DS-metadata (``None`` derives it from the keys, as a genesis
+        bring-up does).  One full rebuild runs here, and no frame is read,
+        so a large table ships no genesis frame.  Given the metadata a
+        genesis bring-up derives, the replica is byte-identical to one
+        brought up from the genesis frame over the same rows.  The cursor
+        starts at the transport's end: the table is current through
+        everything published so far.
+        """
+        rep = cls(transport, backend=backend, backend_opts=backend_opts,
+                  start_pos=transport.end())
+        rep.replica = Replica(keyset, meta=meta, backend=backend,
+                              backend_opts=backend_opts, applied_lsn=applied_lsn)
+        rep.n_rebuilds += 1
+        return rep
+
     # ------------------------------------------------------------- state
     @property
     def applied_lsn(self) -> int:
@@ -669,6 +713,7 @@ class StreamReplica:
         return self.replica.search_batch(query_words)
 
     # -------------------------------------------------------------- poll
+    @spanned("stream.poll")
     def poll(self, max_frames: int | None = None) -> dict:
         """Drain available frames; one incremental rebuild for the span.
 
@@ -683,7 +728,8 @@ class StreamReplica:
         rebuild.  Returns poll stats (frames seen, batches applied,
         duplicates, catch-ups, shed adoptions, the new watermark;
         ``applies`` lists every span's apply stats, ``apply`` keeps the
-        last one).
+        last one; ``decode`` is the seconds spent unpacking, checking and
+        decoding frames).
         """
         seen = 0
         pending: list[ChangeLog] = []
@@ -692,7 +738,7 @@ class StreamReplica:
             "frames": 0, "applied_batches": 0, "duplicates": 0,
             "catchup": False, "truncated_jump": False, "apply": None,
             "applies": [], "shed_adopted": 0, "frames_rejected": 0,
-            "reorder_heals": 0,
+            "reorder_heals": 0, "decode": 0.0,
         }
 
         def _flush_pending():
@@ -719,8 +765,10 @@ class StreamReplica:
                 continue
             if raw is None:
                 break
+            t0 = time.perf_counter()
             try:
-                frame = decode_frame(raw)
+                with span("stream.decode"):
+                    frame = decode_frame(raw)
             except (FrameCorrupt, FrameSchemaError) as err:
                 # a damaged/undecodable frame: apply the drained good
                 # prefix, leave the cursor ON the frame (a re-read may
@@ -730,6 +778,8 @@ class StreamReplica:
                 err.pos = self.pos
                 fail = err
                 break
+            finally:
+                out["decode"] += time.perf_counter() - t0
             seen += 1
             out["frames"] += 1
             if isinstance(frame, ShedFrame):

@@ -93,3 +93,25 @@ def test_lookup_breakdown_leaves_the_copies():
     assert lk["copies_ms"] == pytest.approx(9.0)
     assert b["rebuild"] is None
     assert b["modules"]["jit_lookup"]["runs"] == 2
+
+
+def test_apply_breakdown_splits_each_apply_by_span_and_program():
+    ops = {0: [["a", 10, 20], ["b", 30, 40], ["c", 60, 70], ["d", 80, 85]]}
+    modules = {0: [["jit_merge(1)", 10, 20], ["jit_build_leaf(2)", 30, 40],
+                   ["jit_sort(3)", 60, 70], ["jit_build_leaf(2)", 80, 85]]}
+    spans = [["bench.window", 0, 100],
+             ["bench.apply", 0, 45], ["repro.stream.decode", 1, 4],
+             ["repro.replica.fold", 5, 7], ["bench.backend.merge_sorted", 9, 10],
+             ["bench.backend.build", 29, 30],
+             ["bench.apply", 50, 95], ["repro.stream.decode", 51, 56],
+             ["repro.replica.fold", 57, 58], ["bench.backend.sort", 59, 60],
+             ["bench.backend.build", 79, 80]]
+    b = sb.breakdown(trace.Trace(ops=ops, spans=spans), modules)
+    a = b["apply"]
+    assert a["applies"] == 2
+    assert a["wall_s"] == pytest.approx(45e-9)
+    assert a["jit_merge"] == pytest.approx(5e-9)
+    assert a["jit_sort"] == pytest.approx(5e-9)
+    assert a["repro.stream.decode"] == pytest.approx(4e-9)
+    assert a["repro.replica.fold"] == pytest.approx(1.5e-9)
+    assert b["rebuild"] is None and b["lookup"] is None

@@ -20,6 +20,10 @@ line:
   union of ``jit_sort`` and of ``jit_merge`` intervals, ``sort_device_s``
   as the benchmark reads it, and the wall of each ``repro.rebuild.*`` and
   ``repro.snapshot.*`` span, as means;
+- ``apply`` (catch-up cells): per ``bench.apply``, the union of
+  ``jit_sort`` and of ``jit_merge`` intervals and the wall of each
+  ``repro.stream.*``, ``repro.replica.*`` and ``repro.rebuild.*`` span,
+  as means;
 - ``lookup`` (request cells): per ``bench.lookup``, the wall of
   ``repro.snapshot.pin`` and ``repro.lookup`` in it and the rest (the
   query's copy in, the answers' copies out and the wait for the device),
@@ -99,27 +103,42 @@ def module_table(modules: list, lo: float, hi: float) -> dict:
                                 key=lambda kv: -union_s(kv[1], lo, hi))}
 
 
-def rebuild_breakdown(tr: trace.Trace, mods: list, win) -> dict | None:
-    """Means over the ``bench.rebuild`` spans that start in the window."""
+def span_rows(tr: trace.Trace, mods: list, win, outer: str) -> list[dict]:
+    """Per ``outer`` span that starts in the window: its wall, the wall of
+    each ``repro.*`` span inside, and the union of ``jit_sort`` and of
+    ``jit_merge`` intervals, in seconds."""
     lo, hi = win
-    outers = [s for s in tr.spans if s[0] == "bench.rebuild" and lo <= s[1] < hi]
-    if not outers:
-        return None
-    sort_device = trace.stage_busy_s(tr, win, "bench.rebuild",
-                                     "bench.backend.sort", "bench.backend.build")
     rows = []
-    for outer, sort_s in zip(outers, sort_device):
-        row: dict = {"wall_s": (outer[2] - outer[1]) / 1e9, "sort_device_s": sort_s}
+    for _, a, b in (s for s in tr.spans if s[0] == outer and lo <= s[1] < hi):
+        row: dict = {"wall_s": (b - a) / 1e9}
         for name, s, e in tr.spans:
-            if name.startswith(PREFIX) and outer[1] <= s and e <= outer[2]:
+            if name.startswith(PREFIX) and a <= s and e <= b:
                 row[name] = row.get(name, 0.0) + (e - s) / 1e9
         for prog in ("jit_sort", "jit_merge"):
             row[prog] = union_s([(s, e) for n, s, e in mods
-                                 if program_name(n) == prog], outer[1], outer[2])
+                                 if program_name(n) == prog], a, b)
         rows.append(row)
+    return rows
+
+
+def means(rows: list[dict], count: str) -> dict | None:
+    if not rows:
+        return None
     keys = sorted({k for r in rows for k in r})
-    return {"rebuilds": len(rows),
+    return {count: len(rows),
             **{k: sum(r.get(k, 0.0) for r in rows) / len(rows) for k in keys}}
+
+
+def rebuild_breakdown(tr: trace.Trace, mods: list, win) -> dict | None:
+    """Means over the ``bench.rebuild`` spans that start in the window,
+    with ``sort_device_s`` as the benchmark reads it."""
+    rows = span_rows(tr, mods, win, "bench.rebuild")
+    if rows:
+        sort_device = trace.stage_busy_s(tr, win, "bench.rebuild",
+                                         "bench.backend.sort", "bench.backend.build")
+        for row, sort_s in zip(rows, sort_device):
+            row["sort_device_s"] = sort_s
+    return means(rows, "rebuilds")
 
 
 def lookup_breakdown(spans: list, win) -> dict | None:
@@ -163,6 +182,7 @@ def breakdown(tr: trace.Trace, modules: dict, k: int = GAPS) -> dict:
             "modules": module_table(mods, lo, hi),
             "repro_spans": sum(s[0].startswith(PREFIX) for s in tr.spans),
             "rebuild": rebuild_breakdown(tr, mods, win),
+            "apply": means(span_rows(tr, mods, win, "bench.apply"), "applies"),
             "lookup": lookup_breakdown(tr.spans, win),
             "idle_by_span": dict(sorted(by_span.items(), key=lambda kv: -kv[1])),
             "idle_in_shorter_gaps_s": (hi - lo) / 1e9 - busy - sum(by_span.values()),
