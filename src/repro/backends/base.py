@@ -30,9 +30,10 @@ the same everywhere.
 Compiled-plan execution promotes the remaining serial stages to backend
 ops: ``build`` (§5.3 bulk build — per-level entry programs cached in the
 shared plan cache, with a backend-substitutable partial-key gather) and
-``refresh_meta`` (§4.3 — a cached device program for the adjacent D-bit
-positions plus one host scatter-OR).  Shape-polymorphic ops (sort, merge,
-fused extract+sort, the batched path) run through
+``refresh_meta`` (§4.3 — a cached device program that OR-reduces the
+adjacent D-bits into the compressed-space bitmap, mapped to full-key bits
+on the host).  Shape-polymorphic ops (sort, merge, fused extract+sort,
+the batched path) run through
 ``repro.core.plancache``: inputs pad to power-of-two buckets and the
 compiled program is memoized per ``(op, backend, bucket, n_words,
 static config)``, so drifting sizes under a churny serving load replay
@@ -299,31 +300,27 @@ class ExecutionBackend(abc.ABC):
                      n_valid: int | None = None, donate: bool = False):
         """Stage 4 (§4.3): recompute DS-metadata at the opportune time.
 
-        The adjacent D-bit positions run as a cached, shape-bucketed
-        device program; the scatter-OR into the bitmap words is one
-        vectorized host op (``meta_on_rebuild``).  ``n_valid`` marks
+        A cached, shape-bucketed device program OR-reduces the adjacent
+        D-bits of ``comp_sorted`` into the compressed-space D-bitmap;
+        only those Wc words cross to the host, where ``meta_on_rebuild``
+        maps each set bit through D-offset.  ``n_valid`` marks
         ``comp_sorted`` as bucket-shaped with ``n_valid`` real rows.
-        Only the (n-1,) device dpos vector crosses to the host — the
-        sorted keys themselves stay on device.  ``donate=True`` consumes
-        ``comp_sorted`` — refresh is the pipeline's last consumer of the
-        padded sorted run, so its buffer is reclaimed in place; only pass
-        it when nothing else reads that buffer again.
+        ``donate=True`` consumes ``comp_sorted`` — refresh is the
+        pipeline's last consumer of the padded sorted run, so its buffer
+        is reclaimed in place; only pass it when nothing else reads that
+        buffer again.
         """
         import numpy as np
 
         from repro.core.metadata import meta_on_rebuild
-        from repro.core.plancache import adjacent_dpos_padded
+        from repro.core.plancache import adjacent_dbitmap_padded
 
-        dpos = adjacent_dpos_padded(
+        comp_dbitmap = adjacent_dbitmap_padded(
             jnp.asarray(comp_sorted, jnp.uint32), backend=self.name,
             n_valid=n_valid, donate=donate,
         )
-        # comp_sorted is unused by meta_on_rebuild when dpos_comp is given;
-        # pass an empty view rather than forcing a device->host transfer of
-        # the (possibly bucket-padded) sorted run
-        comp_unused = np.zeros((0, int(comp_sorted.shape[1])), np.uint32)
         return meta_on_rebuild(
-            comp_unused, meta, np.asarray(ref_key), dpos_comp=dpos
+            None, meta, np.asarray(ref_key), comp_dbitmap=comp_dbitmap
         )
 
     # ----------------------------------------------------- batched (many)

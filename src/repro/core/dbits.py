@@ -19,8 +19,6 @@ input — O(n) work on top of the sort, exactly the paper's Remark 1.
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,6 +31,7 @@ __all__ = [
     "rank_in_sorted_keyed",
     "merge_words_keyed",
     "adjacent_dbit_positions",
+    "adjacent_dbitmap",
     "dbit_position_pairwise",
     "positions_to_bitmap",
     "bitmap_to_positions",
@@ -240,6 +239,33 @@ def adjacent_dbit_positions(sorted_words: jnp.ndarray) -> jnp.ndarray:
     return dbit_position_pairwise(sorted_words[:-1], sorted_words[1:])
 
 
+def adjacent_dbitmap(sorted_words: jnp.ndarray, n_valid=None) -> jnp.ndarray:
+    """OR of the adjacent D-bits of sorted keys as a (W,) uint32 bitmap.
+
+    Pair ``i`` (rows ``i`` and ``i + 1``) contributes the most significant
+    set bit of the XOR of its first differing word (MSB-first, as in
+    :func:`positions_to_bitmap`); equal pairs contribute nothing.  Only
+    pairs with ``i + 1 < n_valid`` (default: every row) count, so lanes at
+    and past ``n_valid`` may hold anything.  The first differing word is a
+    static select chain over the W words and the bitmap an OR-reduction:
+    no position vector, no gather, no scatter.
+    """
+    w = jnp.asarray(sorted_words, jnp.uint32)
+    n, n_words = int(w.shape[0]), int(w.shape[1])
+    pair = jnp.arange(max(n - 1, 0), dtype=jnp.uint32)
+    live = pair + 1 < (n if n_valid is None else n_valid)
+    out = []
+    for k in range(n_words):
+        x = w[:-1, k] ^ w[1:, k]
+        msb = jnp.where(x != 0, jnp.uint32(0x80000000) >> jax.lax.clz(x), 0)
+        out.append(jax.lax.reduce(
+            jnp.where(live, msb, jnp.uint32(0)), jnp.uint32(0),
+            jax.lax.bitwise_or, dimensions=(0,),
+        ))
+        live = live & (x == 0)  # a pair's first differing word ends it
+    return jnp.stack(out)
+
+
 def positions_to_bitmap(positions: jnp.ndarray, n_words: int) -> jnp.ndarray:
     """Scatter bit positions into a (n_words,) uint32 bitmap (MSB-first)."""
     valid = positions != NO_DBIT
@@ -293,10 +319,7 @@ def bitmap_popcount(bitmap: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(jax.lax.population_count(bitmap.astype(jnp.uint32)).astype(jnp.int32))
 
 
-@partial(jax.jit, static_argnames=("n_words",))
-def _dbitmap_from_sorted(sorted_words: jnp.ndarray, n_words: int) -> jnp.ndarray:
-    dpos = adjacent_dbit_positions(sorted_words)
-    return positions_to_bitmap(dpos, n_words)
+_dbitmap_from_sorted = jax.jit(adjacent_dbitmap)
 
 
 def compute_dbitmap(words: jnp.ndarray, *, presorted: bool = False) -> jnp.ndarray:
@@ -308,7 +331,7 @@ def compute_dbitmap(words: jnp.ndarray, *, presorted: bool = False) -> jnp.ndarr
     w = jnp.asarray(words, dtype=jnp.uint32)
     if not presorted:
         (w,) = sort_words(w)
-    return _dbitmap_from_sorted(w, int(words.shape[1]))
+    return _dbitmap_from_sorted(w)
 
 
 def compute_variant_bitmap(

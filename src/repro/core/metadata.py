@@ -15,10 +15,10 @@ their correctness arguments are implemented exactly:
 
 The *update rules* (``meta_on_insert`` etc.) are host-side scalar work
 (numpy) — they sit on the DB transaction path.  The *rebuild-time refresh*
-is not host-side-only: since the compiled-plan work landed, the adjacent
-D-bit positions run as a cached, shape-bucketed device program (the
-backends' ``refresh_meta`` op feeds them in via ``dpos_comp``), and only
-the final scatter-OR into the bitmap words happens here on the host.
+is not host-side-only: a cached, shape-bucketed device program OR-reduces
+the adjacent D-bits into the compressed-space bitmap (the backends'
+``refresh_meta`` op feeds its Wc words in via ``comp_dbitmap``), and only
+the mapping of those few bits through D-offset happens here on the host.
 """
 
 from __future__ import annotations
@@ -144,10 +144,10 @@ def meta_on_delete(meta: DSMeta) -> DSMeta:
 
 
 def meta_on_rebuild(
-    comp_sorted: np.ndarray,
+    comp_sorted: np.ndarray | None,
     old_meta: DSMeta,
     ref_full_key: np.ndarray,
-    dpos_comp: np.ndarray | None = None,
+    comp_dbitmap: np.ndarray | None = None,
 ) -> DSMeta:
     """Recompute DS-metadata during index reconstruction (§4.3).
 
@@ -157,34 +157,36 @@ def meta_on_rebuild(
     pass over the table (done by the caller who still holds full keys;
     here we accept the compressed adjacency only).
 
-    The bit set is one vectorized scatter-OR into the 32-bit bitmap words
-    (``np.bitwise_or.at`` is duplicate-safe), not a per-position Python
-    loop.  ``dpos_comp`` optionally carries precomputed adjacent D-bit
-    positions — the pipeline's cached refresh program
-    (``repro.core.plancache.adjacent_dpos_padded``) passes them so the
-    device half of the refresh compiles once per shape bucket.
+    ``comp_dbitmap`` optionally carries the compressed-space D-bitmap
+    (Wc uint32 words) — the pipeline's cached refresh program
+    (``repro.core.plancache.adjacent_dbitmap_padded``) passes it, and
+    ``comp_sorted`` is then unused.  Without it the bitmap is computed
+    from the host array ``comp_sorted``.  Each set compressed bit ``c``
+    sets full-key bit ``d_offset[c]``: at most Wc·32 bits.
     """
-    from .dbits import NO_DBIT
-
-    if dpos_comp is None:
+    if comp_dbitmap is None:
         import jax.numpy as jnp
 
-        from .dbits import adjacent_dbit_positions
+        from .dbits import adjacent_dbitmap
 
-        dpos_comp = np.asarray(
-            adjacent_dbit_positions(jnp.asarray(comp_sorted, jnp.uint32))
-        )
-    dpos_comp = np.asarray(dpos_comp)
+        comp_dbitmap = adjacent_dbitmap(jnp.asarray(comp_sorted, jnp.uint32))
+    # MSB-first bit order: big-endian bytes unpack to compressed positions
+    comp_pos = np.flatnonzero(np.unpackbits(
+        np.asarray(comp_dbitmap, np.uint32).astype(">u4").view(np.uint8)
+    ))
     d_off = old_meta.d_offset()
-    valid = dpos_comp != NO_DBIT
-    full_pos = d_off[dpos_comp[valid]]
-    dbm = np.zeros_like(old_meta.dbitmap)
-    if full_pos.size:
-        np.bitwise_or.at(
-            dbm,
-            full_pos // 32,
-            np.uint32(1) << (31 - (full_pos % 32)).astype(np.uint32),
+    if comp_pos.size and comp_pos[-1] >= d_off.size:
+        raise ValueError(
+            f"compressed D-bit {int(comp_pos[-1])} lies past the "
+            f"{d_off.size} positions of the D-offset"
         )
+    full_pos = d_off[comp_pos]
+    dbm = np.zeros_like(old_meta.dbitmap)
+    np.bitwise_or.at(
+        dbm,
+        full_pos // 32,
+        np.uint32(1) << (31 - (full_pos % 32)).astype(np.uint32),
+    )
     return replace(old_meta, dbitmap=dbm, refkey=np.asarray(ref_full_key, np.uint32))
 
 

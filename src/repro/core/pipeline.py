@@ -245,7 +245,8 @@ class ReconstructionPipeline:
     def refresh_meta(self, comp_sorted, meta: DSMeta, ref_key,
                      n_valid: int | None = None, donate: bool = False) -> DSMeta:
         """Stage 4 (§4.3): recompute DS-metadata at the opportune time
-        (backend-dispatched: cached device dpos program + host scatter-OR)."""
+        (backend-dispatched: a cached device program OR-reduces the adjacent
+        D-bits into Wc words; the host maps them through D-offset)."""
         return self.backend.refresh_meta(comp_sorted, meta, ref_key,
                                          n_valid=n_valid, donate=donate)
 

@@ -39,9 +39,11 @@ Normalization discipline (what keeps byte-identity):
   unpadded result and the pads are sliced off before anything downstream
   sees them.  Because the normalization happens *inside* the program, the
   incoming pad lanes may carry arbitrary garbage.
-* **build / refresh / lookup** — pads are inert garbage lanes: every
-  consumer clips its gathers to the valid count (carried as a dynamic
-  scalar operand) and the padded tail is sliced off host-side.
+* **build / lookup** — pads are inert garbage lanes: every consumer
+  clips its gathers to the valid count (carried as a dynamic scalar
+  operand) and the padded tail is sliced off host-side.
+* **refresh** — every adjacent pair that reaches the valid count is
+  masked out of the OR-reduction, so pad lanes contribute nothing.
 
 Counters: ``hits``/``misses`` count cache lookups; ``traces`` counts
 actual program *tracings* (the Python body of a cached program runs only
@@ -103,7 +105,7 @@ __all__ = [
     "sort_padded",
     "merge_padded",
     "fused_extract_sort_padded",
-    "adjacent_dpos_padded",
+    "adjacent_dbitmap_padded",
     "ChunkPlan",
     "tune_chunking",
 ]
@@ -758,7 +760,7 @@ def fused_extract_sort_padded(
     return ks[:n], rs[:n]
 
 
-def adjacent_dpos_padded(
+def adjacent_dbitmap_padded(
     comp_sorted: jnp.ndarray,
     *,
     backend: str = "jnp",
@@ -766,15 +768,15 @@ def adjacent_dpos_padded(
     n_valid: int | None = None,
     donate: bool = False,
 ) -> np.ndarray:
-    """Adjacent distinction-bit positions of a sorted run, bucketed.
+    """Compressed-space D-bitmap of a sorted run, bucketed.
 
     The refresh stage's device half: one cached program per (backend,
-    bucket, Wc) computes all bucket-1 adjacent D-bit positions over
-    in-program-normalized lanes (pads become all-ones rows, whose
-    adjacencies land past the ``n - 1`` slice); the host half (the
-    scatter-OR into the 32-bit bitmap words) lives in
-    ``repro.core.metadata.meta_on_rebuild``.  Returns (n-1,) int32 with
-    ``NO_DBIT`` at equal-key adjacencies.
+    bucket, Wc) OR-reduces every adjacent pair's distinction bit into Wc
+    words (``repro.core.dbits.adjacent_dbitmap``); pairs that reach lane
+    ``n_valid`` are masked in-program, so pad lanes may hold anything.
+    Only those Wc words cross to the host, where
+    ``repro.core.metadata.meta_on_rebuild`` maps each set bit through
+    D-offset.  Returns (Wc,) uint32.
 
     ``donate=True`` donates the sorted-run operand — refresh is the last
     consumer of the padded sorted keys in the full pipeline, so its
@@ -786,30 +788,25 @@ def adjacent_dpos_padded(
     if n_valid is None:
         n = int(comp_sorted.shape[0])
         if n < 2:
-            return np.zeros((0,), np.int32)
+            return np.zeros((wc,), np.uint32)
         b = bucket_for("refresh", n)
         comp_sorted = pad_tail(jnp.asarray(comp_sorted, jnp.uint32), b, SENTINEL)
     else:
         n = int(n_valid)
         if n < 2:
-            return np.zeros((0,), np.int32)
+            return np.zeros((wc,), np.uint32)
         b = int(comp_sorted.shape[0])
 
     don = bool(donate) and donation_supported()
     jit_kwargs = {"donate_argnums": (0,)} if don else {}
 
     def builder():
-        from .dbits import adjacent_dbit_positions
+        from .dbits import adjacent_dbitmap
 
-        def prog(cp, nv):
-            lane = jnp.arange(cp.shape[0], dtype=jnp.uint32)
-            cp = jnp.where((lane < nv)[:, None], cp, jnp.uint32(SENTINEL))
-            return adjacent_dbit_positions(cp)
+        return cache.jit(adjacent_dbitmap, **jit_kwargs)
 
-        return cache.jit(prog, **jit_kwargs)
-
-    prog = cache.program(("refresh_dpos", backend, b, wc, don), builder)
-    return np.asarray(prog(comp_sorted, np.uint32(n))[: n - 1], np.int32)
+    prog = cache.program(("refresh_dbm", backend, b, wc, don), builder)
+    return np.asarray(prog(comp_sorted, np.uint32(n)), np.uint32)
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ assertion is strong).
 """
 
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -414,6 +415,105 @@ def test_meta_on_rebuild_vectorized_matches_loop(rng):
         want = _meta_on_rebuild_loop_ref(comp, meta, ks.words[0])
         np.testing.assert_array_equal(got.dbitmap, want.dbitmap)
         np.testing.assert_array_equal(got.refkey, want.refkey)
+
+
+def _dup_keyset(rng, n, w=3):
+    """Keys drawn from a pool of ~n/3 distinct keys: sorted runs hold
+    equal adjacent keys (n=2 gives two equal keys, an empty D-bitmap)."""
+    pool = _keyset(rng, max(n // 3, 1), w=w).words
+    words = pool[rng.integers(0, len(pool), size=n)]
+    return KeySet(words=words, lengths=np.full(n, w * 4, np.int32),
+                  rids=np.arange(n, dtype=np.uint32))
+
+
+def _wide_keyset(rng, n, w=3):
+    """Random keys with up to 96 one-hot keys mixed in: their adjacent
+    D-bits spread over every word, so the compressed run is several
+    words wide and pairs first differ in each of them."""
+    ks = _keyset(rng, n, w=w)
+    m = min(n, 32 * w)
+    pos = rng.choice(32 * w, size=m, replace=False)
+    ks.words[:m] = 0
+    ks.words[np.arange(m), pos // 32] = np.uint32(1) << (31 - pos % 32).astype(np.uint32)
+    return ks
+
+
+@pytest.mark.parametrize("n", [1, 2, 255, 257, 1000])
+@pytest.mark.parametrize("path", ["run", "run_incremental"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_refresh_dbitmap_program_matches_loop(rng, backend, path, n):
+    """The refresh program's compressed D-bitmap, mapped through D-offset,
+    is byte-identical to the per-position loop over the sorted run —
+    through the pipeline, and on a bucket-shaped run whose lanes at and
+    past ``n_valid`` hold arbitrary non-sentinel words.  The metadata
+    also covers 40 deleted keys, so the refresh has stale bits to shed."""
+    pipe = _pipe(backend)
+    for ks in (_keyset(rng, n), _dup_keyset(rng, n), _wide_keyset(rng, n)):
+        deleted = _wide_keyset(rng, 40).words
+        meta = meta_from_keys(np.concatenate([ks.words, deleted]))
+        if path == "run":
+            res, folded = pipe.run(ks, meta=meta), ks
+        else:
+            k = n // 4
+            base = KeySet(words=ks.words[: n - k], lengths=ks.lengths[: n - k],
+                          rids=ks.rids[: n - k])
+            delta = KeySet(words=ks.words[n - k:], lengths=ks.lengths[n - k:],
+                           rids=ks.rids[n - k:]) if k else None
+            prev = pipe.run(base, meta=meta)
+            res, folded = pipe.run_incremental(prev, base, delta, meta=meta)
+            assert res.stats["incremental"] is True
+        comp = np.asarray(res.comp_sorted)
+        want = _meta_on_rebuild_loop_ref(comp, meta, folded.words[0])
+        np.testing.assert_array_equal(res.meta.dbitmap, want.dbitmap)
+        np.testing.assert_array_equal(res.meta.refkey, want.refkey)
+
+        b = plancache.bucket_for("refresh", n)
+        padded = rng.integers(0, 2**32 - 1, size=(b, comp.shape[1]),
+                              dtype=np.uint32)
+        padded[:n] = comp
+        cbm = plancache.adjacent_dbitmap_padded(
+            jnp.asarray(padded), backend=backend, n_valid=n
+        )
+        got = meta_on_rebuild(None, meta, folded.words[0], comp_dbitmap=cbm)
+        np.testing.assert_array_equal(got.dbitmap, want.dbitmap)
+
+
+def test_refresh_program_has_no_gather_or_scatter(rng):
+    """The refresh program is one OR-reduction: no gather, no scatter in
+    its HLO, a (Wc,) uint32 result, and no retrace on a warm call in the
+    same bucket."""
+    cache = plancache.PlanCache()
+    comp = rng.integers(0, 2**32, size=(700, 2), dtype=np.uint32)
+    comp = comp[np.lexsort(comp.T[::-1])]
+    wc = comp.shape[1]
+    out = plancache.adjacent_dbitmap_padded(jnp.asarray(comp), cache=cache)
+    assert out.shape == (wc,) and out.dtype == np.uint32
+    ((key, prog),) = cache.programs.items()
+    assert key[0] == "refresh_dbm"
+    b = key[2]
+    lowered = prog.lower(jnp.zeros((b, wc), jnp.uint32), np.uint32(b))
+    op = re.compile(r"stablehlo\.(gather|scatter)\b| (gather|scatter)\(")
+    for text in (lowered.as_text(), lowered.compile().as_text()):
+        assert not op.search(text)
+    result = lowered.out_info
+    assert (result.shape, result.dtype) == ((wc,), jnp.uint32)
+
+    t0 = cache.stats()["traces"]
+    plancache.adjacent_dbitmap_padded(jnp.asarray(comp[:690]), cache=cache)
+    padded = plancache.pad_tail(jnp.asarray(comp), b, 0)
+    plancache.adjacent_dbitmap_padded(padded, cache=cache, n_valid=650)
+    assert cache.stats()["traces"] == t0, cache.stats()
+
+
+def test_meta_on_rebuild_rejects_bit_past_d_offset(rng):
+    """A compressed D-bit with no D-offset entry is refused, not mapped."""
+    ks = _keyset(rng, 300)
+    meta = meta_from_keys(ks.words)
+    n_off = meta.d_offset().size
+    cbm = np.zeros((n_off + 31) // 32 + 1, np.uint32)
+    cbm[n_off // 32] |= np.uint32(1) << np.uint32(31 - n_off % 32)
+    with pytest.raises(ValueError, match="D-offset"):
+        meta_on_rebuild(None, meta, ks.words[0], comp_dbitmap=cbm)
 
 
 # ---------------------------------------------------------------------------

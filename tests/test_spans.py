@@ -77,7 +77,7 @@ def test_programs_are_named_after_their_op_family(caplog):
         by_op = {key[0]: (key, prog) for key, prog in cache.programs.items()}
         tree = cell.current.tree
 
-    assert {"sort", "merge", "build_leaf", "build_level", "refresh_dpos",
+    assert {"sort", "merge", "build_leaf", "build_level", "refresh_dbm",
             "lookup"} <= set(by_op)
     u32 = jnp.uint32
     (_, _, b, w, _), sort = by_op["sort"]
@@ -94,7 +94,7 @@ def test_programs_are_named_after_their_op_family(caplog):
     assert "module @jit_lookup " in text
 
     compiled = set(re.findall(r"Compiling jit\((\w+)\)", caplog.text))
-    assert {"sort", "merge", "build_leaf", "build_level", "refresh_dpos",
+    assert {"sort", "merge", "build_leaf", "build_level", "refresh_dbm",
             "lookup"} <= compiled
     assert "traced" not in compiled
 
